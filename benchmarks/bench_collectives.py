@@ -55,7 +55,7 @@ def _trace_payload_events(scheme, hier: bool, elems: int):
         fn = lambda a: comms.hier_all_reduce(a, "data", "node", "dp")  # noqa: E731
     else:
         fn = lambda a: comms.psum(a, ("node", "data"), "dp")           # noqa: E731
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(P(("node", "data")),),
         out_specs=P(("node", "data")), check_vma=False))
     with schemes.use(scheme), comms.record_traffic() as events:
@@ -99,7 +99,7 @@ def _trace_model_payload(scheme, hier: bool, op: str, elems: int):
     else:  # ep_all_to_all
         fn = lambda a: comms.all_to_all(a, axis, 0, 0, "ep")       # noqa: E731
         shape = (64, elems // 8)
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(P(("tpnode", "model")),),
         out_specs=P(("tpnode", "model")), check_vma=False))
     with schemes.use(scheme), comms.record_traffic() as events:
@@ -140,7 +140,7 @@ def _trace_stage_handoff(scheme, hier: bool, elems: int):
     from repro.core.compat import AxisPair
     mesh = compat.make_mesh((2, 2, 2), ("data", "ppnode", "stage"))
     axis = AxisPair("ppnode", "stage") if hier else ("ppnode", "stage")
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         lambda a: comms.stage_send(a, axis),
         mesh=mesh, in_specs=(P("data"),), out_specs=P("data"),
         check_vma=False))

@@ -128,8 +128,8 @@ def _psum_us(codec_name: str, elems: int) -> float:
         with policy_lib.use_plan(plan):
             return comms.psum(a, "x", "dp")
 
-    sm = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(P("x"),),
-                                  out_specs=P("x"), check_vma=False))
+    sm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("x"),),
+                               out_specs=P("x"), check_vma=False))
     x = jnp.asarray(np.random.default_rng(0).normal(
         size=(8, elems)).astype(np.float32))
     us = _time_us(sm, x)

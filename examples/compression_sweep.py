@@ -71,10 +71,10 @@ def main():
         params, ostate, cstate = trainer.init_all(jax.random.key(0))
         with comms.record_traffic() as events:
             trainer.step.lower(
-                jax.tree.map(compat.typeof, params),
-                jax.tree.map(compat.typeof, ostate),
-                jax.tree.map(compat.typeof, cstate),
-                {k: compat.typeof(jax.numpy.asarray(v))
+                jax.tree.map(jax.typeof, params),
+                jax.tree.map(jax.typeof, ostate),
+                jax.tree.map(jax.typeof, cstate),
+                {k: jax.typeof(jax.numpy.asarray(v))
                  for k, v in data.batch(0).items()})
         led = rl.ledger_summary(events, train=True)
         if pol.name == "baseline":

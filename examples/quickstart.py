@@ -41,10 +41,10 @@ def main():
         # trace once under the ledger to see what crosses the wire
         with comms.record_traffic() as events:
             trainer.step.lower(
-                jax.tree.map(lambda x: compat.typeof(x), params),
-                jax.tree.map(lambda x: compat.typeof(x), ostate),
-                jax.tree.map(lambda x: compat.typeof(x), cstate),
-                jax.tree.map(lambda x: compat.typeof(x), batch))
+                jax.tree.map(lambda x: jax.typeof(x), params),
+                jax.tree.map(lambda x: jax.typeof(x), ostate),
+                jax.tree.map(lambda x: jax.typeof(x), cstate),
+                jax.tree.map(lambda x: jax.typeof(x), batch))
         led = rl.ledger_summary(events, train=True)
         # and actually run a few steps
         losses = []

@@ -39,6 +39,7 @@ def main():
                "--prompt-len", "16", "--gen", "6"] + extra
         env = dict(os.environ)
         env["PYTHONPATH"] = str(ROOT / "src")
+        env["JAX_PLATFORMS"] = "cpu"      # the meshes run on host devices
         env.pop("XLA_FLAGS", None)
         print(f"=== {title} ===")
         proc = subprocess.run(cmd, env=env, text=True, capture_output=True)

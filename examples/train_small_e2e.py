@@ -38,6 +38,7 @@ def run_incarnation(args, steps, dp, tp, ckpt, resume):
     import os
     env.update({k: v for k, v in os.environ.items()
                 if k not in ("XLA_FLAGS", "PYTHONPATH")})
+    env["JAX_PLATFORMS"] = "cpu"          # the meshes run on host devices
     print("+", " ".join(cmd))
     proc = subprocess.run(cmd, env=env, text=True, capture_output=True)
     print(proc.stdout)

@@ -524,8 +524,9 @@ class vma_mode:
     """Whether the surrounding shard_map tracks varying-manual-axes.
 
     The train step runs with ``check_vma=False`` (see train_step.py); in
-    that mode every value is typed with an empty vma and ``pvary`` must NOT
-    be inserted — its transpose (psum_invariant) rejects untyped values.
+    that mode every value is typed with an empty vma and
+    ``pcast(to="varying")`` must NOT be inserted — its transpose
+    (psum_invariant) rejects untyped values.
     All vma-cast helpers below become no-ops when this flag is off."""
 
     def __init__(self, checked: bool):
@@ -542,23 +543,20 @@ class vma_mode:
 
 
 def _vma_checked() -> bool:
-    if not compat.HAS_VMA:
-        return False
     return getattr(_vma, "checked", True)
 
 
 def _ensure_varying(x, axis):
-    """pvary iff not already varying over ``axis`` (pvary is not idempotent).
+    """Cast to varying over ``axis`` iff not already (not idempotent).
 
     ``axis`` may be a name or a tuple of names (joint / factored axes)."""
     if not _vma_checked():
         return x
     axes = tuple(axis) if isinstance(axis, (tuple, list)) else (axis,)
-    vma = getattr(compat.typeof(x), "vma", frozenset())
-    need = tuple(ax for ax in axes if ax not in vma)
+    need = tuple(ax for ax in axes if ax not in jax.typeof(x).vma)
     if not need:
         return x
-    return compat.pvary(x, need)
+    return lax.pcast(x, need, to="varying")
 
 
 # --------------------------------------------------------------------------
@@ -1631,7 +1629,7 @@ _hier_f_vjp.defvjp(_hier_f_fwd, _hier_f_bwd)
 
 
 def match_vma(x, like):
-    """pvary pytree ``x`` so its varying-axes type matches ``like``'s leaves.
+    """Cast pytree ``x`` so its varying-axes type matches ``like``'s leaves.
 
     Needed wherever a freshly-created zeros/ones scan seed meets values that
     came through collectives (scan carries must be vma-stable)."""
@@ -1639,19 +1637,18 @@ def match_vma(x, like):
         return x
     vma = frozenset()
     for l in jax.tree_util.tree_leaves(like):
-        vma = vma | getattr(compat.typeof(l), "vma", frozenset())
+        vma = vma | jax.typeof(l).vma
 
     def f(l):
-        cur = getattr(compat.typeof(l), "vma", frozenset())
-        need = tuple(vma - cur)
-        return compat.pvary(l, need) if need else l
+        need = tuple(vma - jax.typeof(l).vma)
+        return lax.pcast(l, need, to="varying") if need else l
     return jax.tree.map(f, x)
 
 
 def varying_all(x, axes):
-    """pvary a pytree onto every mesh axis (idempotent) — used to give scan
-    carries a stable vma type regardless of which collectives produced
-    them."""
+    """Cast a pytree to varying over every mesh axis (idempotent) — used
+    to give scan carries a stable vma type regardless of which collectives
+    produced them."""
     if not _vma_checked():
         return x
 

@@ -1,23 +1,12 @@
-"""Version-portability shims over the jax API surface this repo uses.
+"""Mesh and named-axis helpers shared by the model, comms and launch code.
 
-The codebase is written against the modern jax API (``jax.shard_map``,
-``jax.typeof``/``lax.pvary`` varying-manual-axes typing, ``AxisType``
-meshes, ``lax.axis_size``); pinned container images may carry an older
-0.4.x release where those live elsewhere or do not exist.  Every call
-site goes through this module so the rest of the code reads as if the
-modern API were always present.
-
-Semantics of the fallbacks:
-
-* ``shard_map`` — modern ``check_vma`` maps onto legacy ``check_rep``.
-  On legacy jax we always disable the replication checker: it predates
-  ``custom_vjp`` rep rules and rejects the compression primitives.
-* ``pvary``/``typeof`` — legacy jax has no varying-manual-axes types, so
-  ``pvary`` is the identity and avals carry no ``vma`` set.  ``HAS_VMA``
-  lets callers skip vma bookkeeping entirely on legacy jax.
-* ``axis_size`` — ``lax.psum`` of a python literal is evaluated
-  statically inside ``shard_map``/``pmap`` tracing on every jax version,
-  which is the classic way to read a named axis size as an int.
+* ``AxisPair`` — a node-factored mesh axis that the collectives in
+  :mod:`repro.core.comms` dispatch on.
+* ``make_mesh`` — ``jax.make_mesh`` with ``Auto`` axis types: the step
+  functions run under ``shard_map`` and place nothing explicitly.
+* ``axis_size``/``axis_index`` — a tuple of axis names (an ``AxisPair``
+  among them) gives the joint size and the outer-major linear index,
+  matching mesh construction order.
 """
 
 from __future__ import annotations
@@ -26,8 +15,6 @@ import typing
 
 import jax
 from jax import lax
-
-HAS_VMA = hasattr(lax, "pvary")
 
 
 class AxisPair(typing.NamedTuple):
@@ -50,38 +37,10 @@ class AxisPair(typing.NamedTuple):
 
 
 def make_mesh(shape, axes, *, devices=None):
-    """jax.make_mesh with Auto axis_types when the installed jax has them."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    try:
-        return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
-    except TypeError:
-        kwargs.pop("axis_types", None)
-        return jax.make_mesh(tuple(shape), tuple(axes), **kwargs)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _legacy
-    return _legacy(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
-
-def typeof(x):
-    if hasattr(jax, "typeof"):
-        return jax.typeof(x)
-    return jax.core.get_aval(x)
-
-
-def pvary(x, axes):
-    if HAS_VMA:
-        return lax.pvary(x, tuple(axes))
-    return x
+    """jax.make_mesh with every axis typed ``Auto``."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def axis_size(axis) -> int:
@@ -91,9 +50,7 @@ def axis_size(axis) -> int:
         for ax in axis:
             n *= axis_size(ax)
         return n
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis)
-    return lax.psum(1, axis)
+    return lax.axis_size(axis)
 
 
 def axis_index(axis):

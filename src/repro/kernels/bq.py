@@ -20,8 +20,14 @@ Four kernels:
     only — the reduce-scatter tail that keeps the f32 chunk and sends
     nothing further, so the re-encode is skipped too.
 
+Rate 4 packs two values per byte: byte j of a row holds lane j in its high
+nibble and lane j + 64 in its low one, two lane-half slices that Mosaic
+lowers without a shape cast across lanes.
+
 All kernels are bit-identical to the ``ref.py`` oracles (same jnp rounding
-primitives) and are validated in ``interpret=True`` mode on CPU.
+primitives): validated in ``interpret=True`` mode on CPU, compiled for a
+described v5e in ``tests/test_tpu_compile.py``, and checked against the
+oracles on the chip by ``chip_smoke.py``.
 """
 
 from __future__ import annotations
@@ -53,8 +59,8 @@ def _quantize(x, bits: int):
     qmax = _QMAX[bits]
     q = jnp.clip(jnp.round(x / scale * qmax), -qmax, qmax).astype(jnp.int32)
     if bits == 4:
-        qq = (q + 8).reshape(*q.shape[:-1], q.shape[-1] // 2, 2)
-        packed = (qq[..., 0] << 4) | qq[..., 1]
+        half = q.shape[-1] // 2
+        packed = ((q[..., :half] + 8) << 4) | (q[..., half:] + 8)
         return packed.astype(jnp.uint8), None, scale
     if bits == 24:
         return (q >> 8).astype(jnp.int16), (q & 0xFF).astype(jnp.uint8), scale
@@ -64,8 +70,7 @@ def _quantize(x, bits: int):
 def _dequantize(q_hi, q_lo, scale, bits: int):
     if bits == 4:
         p = q_hi.astype(jnp.int32)
-        q = jnp.stack([(p >> 4) - 8, (p & 0xF) - 8], axis=-1)
-        q = q.reshape(*p.shape[:-1], p.shape[-1] * 2)
+        q = jnp.concatenate([(p >> 4) - 8, (p & 0xF) - 8], axis=-1)
     elif bits == 24:
         q = q_hi.astype(jnp.int32) * 256 + q_lo.astype(jnp.int32)
     else:

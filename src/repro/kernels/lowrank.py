@@ -85,13 +85,18 @@ def from_mat(mat: jnp.ndarray, n: int) -> jnp.ndarray:
 # --------------------------------------------------------------------------
 
 def matmul_ref(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Oracle: f32 matmul with an f32 accumulator (the kernel's contract)."""
+    """Oracle: f32 matmul with an f32 accumulator (the kernel's contract).
+
+    ``HIGHEST`` keeps the TPU from rounding the f32 operands to bf16 — on
+    the CPU it changes nothing."""
     return jnp.dot(a.astype(jnp.float32), b.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST,
                    preferred_element_type=jnp.float32)
 
 
 def _mm_kernel(a_ref, b_ref, o_ref):
     o_ref[...] = jnp.dot(a_ref[...], b_ref[...],
+                         precision=jax.lax.Precision.HIGHEST,
                          preferred_element_type=jnp.float32)
 
 

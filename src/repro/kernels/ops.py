@@ -84,27 +84,42 @@ def from_blocks(x2d: jnp.ndarray, shape, dtype=jnp.float32) -> jnp.ndarray:
 
 # --------------------------------------------------------------------------
 # block-matrix level ops (used directly by the ring collectives)
+#
+# Block matrices may carry leading dims (``[n, M, 128]``: the n shards of a
+# gathered wire).  The oracles take any leading shape; the kernels tile a
+# 2-D ``(rows, width)`` matrix, so the Pallas branches fold the leading
+# dims into rows and unfold the results.
 # --------------------------------------------------------------------------
 
+def _rows(a):
+    return None if a is None else a.reshape(-1, a.shape[-1])
+
+
+def _unrows(a, lead):
+    return None if a is None else a.reshape(*lead, a.shape[-1])
+
+
 def bq_encode_blocks(x2d: jnp.ndarray, bits: int, backend: str | None = None):
-    """(M,128) f32 -> wire dict {q_hi, q_lo|None, scale}."""
+    """(..., M,128) f32 -> wire dict {q_hi, q_lo|None, scale}."""
     be = _resolve(backend)
     if be == "jnp":
         hi, lo, scale = _encode_ref(x2d, bits=bits)
     else:
-        hi, lo, scale = bq.bq_encode_pallas(
-            x2d, bits, interpret=(be == "pallas_interpret"))
+        out = bq.bq_encode_pallas(_rows(x2d), bits,
+                                  interpret=(be == "pallas_interpret"))
+        hi, lo, scale = (_unrows(a, x2d.shape[:-1]) for a in out)
     return {"q_hi": hi, "q_lo": lo, "scale": scale}
 
 
 def bq_decode_blocks(wire: dict, bits: int, backend: str | None = None) -> jnp.ndarray:
-    """wire dict -> (M,128) f32."""
+    """wire dict -> (..., M,128) f32."""
     be = _resolve(backend)
     if be == "jnp":
         return _decode_ref(wire["q_hi"], wire["q_lo"], wire["scale"], bits=bits)
-    return bq.bq_decode_pallas(
-        wire["q_hi"], wire["q_lo"], wire["scale"], bits,
+    out = bq.bq_decode_pallas(
+        _rows(wire["q_hi"]), _rows(wire["q_lo"]), _rows(wire["scale"]), bits,
         interpret=(be == "pallas_interpret"))
+    return _unrows(out, wire["scale"].shape[:-1])
 
 
 def bq_decode_add_encode_blocks(wire: dict, local2d: jnp.ndarray, bits: int,
@@ -126,22 +141,26 @@ def bq_decode_add_encode_blocks(wire: dict, local2d: jnp.ndarray, bits: int,
                 bits=bits)
             s = None
     else:
-        hi, lo, scale, s = bq.bq_decode_add_encode_pallas(
-            wire["q_hi"], wire["q_lo"], wire["scale"], local2d, bits,
-            want_sum=want_sum, interpret=(be == "pallas_interpret"))
+        out = bq.bq_decode_add_encode_pallas(
+            _rows(wire["q_hi"]), _rows(wire["q_lo"]), _rows(wire["scale"]),
+            _rows(local2d), bits, want_sum=want_sum,
+            interpret=(be == "pallas_interpret"))
+        hi, lo, scale, s = (_unrows(a, local2d.shape[:-1]) for a in out)
     return {"q_hi": hi, "q_lo": lo, "scale": scale}, s
 
 
 def bq_decode_add_blocks(wire: dict, local2d: jnp.ndarray, bits: int,
                          backend: str | None = None) -> jnp.ndarray:
-    """Final ring hop: local + decode(wire) -> (M,128) f32, no re-encode."""
+    """Final ring hop: local + decode(wire) -> (..., M,128) f32, no
+    re-encode."""
     be = _resolve(backend)
     if be == "jnp":
         return _da_ref(wire["q_hi"], wire["q_lo"], wire["scale"], local2d,
                        bits=bits)
-    return bq.bq_decode_add_pallas(
-        wire["q_hi"], wire["q_lo"], wire["scale"], local2d, bits,
-        interpret=(be == "pallas_interpret"))
+    out = bq.bq_decode_add_pallas(
+        _rows(wire["q_hi"]), _rows(wire["q_lo"]), _rows(wire["scale"]),
+        _rows(local2d), bits, interpret=(be == "pallas_interpret"))
+    return _unrows(out, local2d.shape[:-1])
 
 
 def bq_gather_decode(wire: dict, idx, bits: int,

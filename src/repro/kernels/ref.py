@@ -45,6 +45,8 @@ def bq_encode_ref(x: jnp.ndarray, bits: int):
     """Quantize (..., BLOCK) float32 into fixed-rate mantissas + per-block scale.
 
     Returns (q_hi, q_lo, scale):
+      bits=4  -> q_hi uint8 (..., BLOCK/2): byte j holds lane j in its high
+                 nibble and lane j + BLOCK/2 in its low nibble, q_lo None
       bits=8  -> q_hi int8  (..., BLOCK), q_lo None
       bits=16 -> q_hi int16 (..., BLOCK), q_lo None
       bits=24 -> q_hi int16 (top 16 bits), q_lo uint8 (bottom 8 bits)
@@ -56,9 +58,10 @@ def bq_encode_ref(x: jnp.ndarray, bits: int):
     qmax = _QMAX[bits]
     q = jnp.clip(jnp.round(x / scale * qmax), -qmax, qmax).astype(jnp.int32)
     if bits == 4:
-        # nibble-pack adjacent pairs: (q+8) fits 4 bits
-        qq = (q + 8).reshape(*q.shape[:-1], q.shape[-1] // 2, 2)
-        packed = (qq[..., 0] << 4) | qq[..., 1]
+        # nibble-pack lane j with lane j + BLOCK/2: (q+8) fits 4 bits, and
+        # pairing the two lane halves needs no shape cast across lanes
+        half = q.shape[-1] // 2
+        packed = ((q[..., :half] + 8) << 4) | (q[..., half:] + 8)
         return packed.astype(jnp.uint8), None, scale
     if bits == 8:
         return q.astype(jnp.int8), None, scale
@@ -75,10 +78,7 @@ def bq_decode_ref(q_hi: jnp.ndarray, q_lo, scale: jnp.ndarray, bits: int) -> jnp
     _check_bits(bits)
     if bits == 4:
         p = q_hi.astype(jnp.int32)
-        a = (p >> 4) - 8
-        b = (p & 0xF) - 8
-        q = jnp.stack([a, b], axis=-1).reshape(*p.shape[:-1],
-                                               p.shape[-1] * 2)
+        q = jnp.concatenate([(p >> 4) - 8, (p & 0xF) - 8], axis=-1)
     elif bits == 24:
         q = q_hi.astype(jnp.int32) * 256 + q_lo.astype(jnp.int32)
     else:

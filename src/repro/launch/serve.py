@@ -1,15 +1,21 @@
 """Serving entrypoint: batched, paged-continuous, and disaggregated modes.
 
+On the CPU the mesh runs on host devices, which JAX gives only to a run
+with ``JAX_PLATFORMS=cpu``; on an accelerator it takes the devices it has.
+
     # classic batched prefill + greedy decode
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --reduced \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+        --arch gemma3-1b --reduced \
         --dp 2 --tp 4 --batch 4 --prompt-len 16 --gen 8 --scheme baseline
 
     # continuous batching over a paged KV pool, quantized at rest
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --reduced \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+        --arch gemma3-1b --reduced \
         --mode paged --kv-codec bq8 --slots 4 --batch 8 --gen 8
 
     # prefill/decode disaggregation with a compressed KV handoff
-    PYTHONPATH=src python -m repro.launch.serve --arch gemma3-1b --reduced \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.serve \
+        --arch gemma3-1b --reduced \
         --mode disagg --dp 2 --tp 2 --kv-codec bq16 --batch 4 --gen 8
 
 The policy flags (--scheme / --codec-for / --no-compress-below) and ring
@@ -22,37 +28,10 @@ at-rest page codec).
 from __future__ import annotations
 
 import argparse
-import os
 import time
 
-
-def _policy_from_flags(ap, args):
-    """scheme + override flags -> CommPolicy (same semantics as train)."""
-    from repro.core import policy as policy_lib
-    comm_policy = policy_lib.as_policy(args.scheme)
-    overrides = []
-    if args.no_compress_below > 0:
-        overrides.append(policy_lib.Rule(
-            "none", max_bytes=args.no_compress_below))
-    for spec in args.codec_for:
-        pat, _, codec = spec.partition("=")
-        if not pat or not codec:
-            ap.error(f"--codec-for wants [DIM@]NAME_GLOB=CODEC, got {spec!r}")
-        dim, at, name = pat.partition("@")
-        try:
-            if at and dim:                       # kv@prefill*=bq8
-                overrides.append(policy_lib.Rule(codec, dim=dim,
-                                                 name=name or None))
-            elif pat in policy_lib.DIMS:         # kv=bq16 (whole dimension)
-                overrides.append(policy_lib.Rule(codec, dim=pat))
-            else:                                # attn*=bq16 (name glob)
-                overrides.append(policy_lib.Rule(codec, name=pat))
-        except KeyError as e:                    # eager codec/dim validation
-            ap.error(f"--codec-for {spec!r}: {e}")
-    if overrides:
-        comm_policy = comm_policy.with_rules(
-            *overrides, name=f"{comm_policy.name}+cli")
-    return comm_policy
+from repro.launch import runtime
+from repro.launch.train import comm_policy_from_flags
 
 
 def main():
@@ -113,10 +92,9 @@ def main():
     args = ap.parse_args()
 
     n_dev = args.dp * args.tp * (2 if args.mode == "disagg" else 1)
-    if n_dev > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={n_dev} "
-            + os.environ.get("XLA_FLAGS", ""))
+    runtime.force_cpu_devices(n_dev)
+    runtime.use_compile_cache()
+    runtime.require_devices(n_dev)
 
     import numpy as np
 
@@ -125,7 +103,10 @@ def main():
     cfg = configs.get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
-    comm_policy = _policy_from_flags(ap, args)
+    try:
+        comm_policy = comm_policy_from_flags(args)
+    except ValueError as e:
+        ap.error(str(e))
     rng = np.random.default_rng(args.seed)
     B, S = args.batch, args.prompt_len
     prompts = rng.integers(0, cfg.vocab_size, (B, S)).astype(np.int32)

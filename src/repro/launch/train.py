@@ -1,29 +1,39 @@
-"""Training entrypoint (CPU-runnable at reduced scale; mesh-parametric).
+"""Training entrypoint (mesh-parametric; CPU-runnable at reduced scale).
 
-    PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b --reduced \
+On the CPU the mesh runs on host devices, which JAX gives only to a run
+that is on the CPU: ``JAX_PLATFORMS=cpu`` or ``--host-devices N``.  On an
+accelerator the mesh takes the devices it has.  ``run(args, cfg)`` is the
+same training loop for callers in the same process (``chip_smoke.py``).
+
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+        --arch gemma3-1b --reduced \
         --dp 2 --tp 4 --steps 50 --scheme zhybrid_16_8 --ckpt-dir /tmp/ck
 
     # pipeline-parallel: 2 stages, 4 microbatches (1F1B), compressed
     # stage handoffs per the active scheme's pp codecs
-    PYTHONPATH=src python -m repro.launch.train --arch qwen2-72b --reduced \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+        --arch qwen2-72b --reduced \
         --dp 2 --tp 2 --pp 2 --microbatches 4 --scheme hier_tpp_8_16
 
     # context-parallel long sequences: zigzag sequence sharding over an
     # explicit 'cp' mesh axis; ring attention rotates KV blocks under the
     # scheme's cp_fwd/cp_bwd codecs
-    PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b --reduced \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+        --arch gemma3-1b --reduced \
         --dp 2 --cp 2 --seq 128 --scheme zhybrid_16_8
 
     # rule-based policy overrides on top of any scheme: small payloads
     # ride raw, embedding gathers stay mild
-    PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b --reduced \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+        --arch gemma3-1b --reduced \
         --dp 2 --tp 2 --scheme zhybrid_16_8 \
         --no-compress-below 65536 --codec-for 'embed*=bq16'
 
     # carried-state codecs on the DP gradient sync: error-feedback bq4
     # (convergence-safe aggressive rate) scoped to the ZeRO-1 grad site;
     # the codec state checkpoints/restores next to the optimizer state
-    PYTHONPATH=src python -m repro.launch.train --arch gemma3-1b --reduced \
+    JAX_PLATFORMS=cpu PYTHONPATH=src python -m repro.launch.train \
+        --arch gemma3-1b --reduced \
         --dp 4 --tp 2 --scheme zhybrid_16_8 \
         --codec-for 'dp@zero1_grad*=ef:bq4' --ckpt-dir /tmp/ck
 
@@ -42,15 +52,20 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import time
+
+from repro.launch import runtime
 
 
 def _restore_opt(trainer, params, opt_dir, step, mesh, checkpoint):
     """Resume the optimizer state saved alongside the params.
 
-    Compat paths: a pre-opt-checkpoint run (no ``opt/`` subdir) or an
-    elastic restart whose new topology changes the opt-state layout both
-    fall back to ``opt_init`` — with a loud warning, since that resets
-    the Adam moments (the bug this replaces did it silently)."""
+    Compat paths: a pre-opt-checkpoint run (no ``opt/`` subdir), an
+    elastic restart whose new topology changes the opt-state layout, and
+    a ``v`` saved in a layout this run does not keep (``v_layout`` in the
+    manifest) all fall back to ``opt_init`` — with a loud warning, since
+    that resets the Adam moments (the bug this replaces did it
+    silently)."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -63,8 +78,14 @@ def _restore_opt(trainer, params, opt_dir, step, mesh, checkpoint):
         lambda sp: NamedSharding(mesh, sp), trainer.opt_state_specs(),
         is_leaf=lambda x: isinstance(x, PartitionSpec))
     try:
-        ostate, _ = checkpoint.restore(opt_dir, ostructs, step=step,
-                                       shardings=osharding)
+        ostate, man = checkpoint.restore(opt_dir, ostructs, step=step,
+                                         shardings=osharding)
+        # untagged checkpoints predate the tag and hold v as is; an 8-bit
+        # one of those read as sqrt(v) would make every Adam step too large
+        saved = man["extra"].get("v_layout", "v")
+        if saved != trainer.opt.v_layout:
+            raise ValueError(f"its v is saved as {saved!r}, this run "
+                             f"keeps {trainer.opt.v_layout!r}")
         print(f"restored optimizer state at step {step}")
         return ostate
     except (ValueError, AssertionError) as e:
@@ -138,7 +159,7 @@ def _restore_tune(trainer, tune_dir, step, mesh, checkpoint):
         return None
 
 
-def main():
+def _parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -192,7 +213,7 @@ def main():
                          "an optional +offload suffix parking matmul "
                          "residuals in pinned host memory")
     ap.add_argument("--host-devices", type=int, default=0,
-                    help="force N XLA host devices (set before jax init)")
+                    help="run on N host CPU devices (pins JAX_PLATFORMS=cpu)")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--global-batch", type=int, default=8)
@@ -256,48 +277,21 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
 
-    n_dev = args.host_devices or (args.dp * args.tp * args.pp * args.cp
-                                  * args.pod)
-    if n_dev > 1:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={n_dev} "
-            + os.environ.get("XLA_FLAGS", ""))
 
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec
+def parse_args(argv=None) -> argparse.Namespace:
+    """The command line as :func:`run` takes it."""
+    return _parser().parse_args(argv)
 
-    from repro import configs
-    from repro.data.pipeline import DataConfig, SyntheticCorpus
-    from repro.launch.mesh import make_mesh, parse_nodes_spec, validate_vpp
-    from repro.models.model import Model
-    from repro.models.params import MeshInfo
-    from repro.train import checkpoint, fault
-    from repro.train.optimizer import AdamConfig
-    from repro.train.train_step import (batch_specs, make_trainer,
-                                        zigzag_shard_seq)
 
-    cfg = configs.get(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    if args.layers:
-        cfg = cfg.replace(n_layers=args.layers, groups=())
-    nodes = parse_nodes_spec(args.nodes, args.dp)
-    tp_nodes = parse_nodes_spec(args.tp_nodes, args.tp, flag="--tp-nodes")
-    pp_nodes = parse_nodes_spec(args.pp_nodes, args.pp, flag="--pp-nodes")
-    cp_nodes = parse_nodes_spec(args.cp_nodes, args.cp, flag="--cp-nodes")
-    mesh = make_mesh(args.dp, args.tp, args.pod, nodes=nodes,
-                     tp_nodes=tp_nodes, pp=args.pp, pp_nodes=pp_nodes,
-                     cp=args.cp, cp_nodes=cp_nodes)
-    mi = MeshInfo.from_mesh(mesh)
-    validate_vpp(args.vpp, args.pp, args.microbatches)
-    model = Model(cfg, mi, vpp=args.vpp)
+def comm_policy_from_flags(args):
+    """The named scheme plus the policy flags' override rules (also the
+    serving launcher's).
 
-    # the named scheme is sugar over rules (the adapter path); the policy
-    # flags prepend override rules, first-match-wins
+    The scheme is sugar over rules (the adapter path); the flags prepend
+    override rules, first-match-wins.  A malformed ``--codec-for`` raises
+    ``ValueError``."""
     from repro.core import policy as policy_lib
     comm_policy = policy_lib.as_policy(args.scheme)
     overrides = []
@@ -307,7 +301,8 @@ def main():
     for spec in args.codec_for:
         pat, _, codec = spec.partition("=")
         if not pat or not codec:
-            ap.error(f"--codec-for wants [DIM@]NAME_GLOB=CODEC, got {spec!r}")
+            raise ValueError(
+                f"--codec-for wants [DIM@]NAME_GLOB=CODEC, got {spec!r}")
         dim, at, name = pat.partition("@")
         try:
             if at and dim:                       # dp@zero1_grad*=ef:bq4
@@ -318,10 +313,75 @@ def main():
             else:                                # embed*=bq16 (name glob)
                 overrides.append(policy_lib.Rule(codec, name=pat))
         except KeyError as e:                    # eager codec/dim validation
-            ap.error(f"--codec-for {spec!r}: {e}")
+            raise ValueError(f"--codec-for {spec!r}: {e}") from None
     if overrides:
         comm_policy = comm_policy.with_rules(
             *overrides, name=f"{comm_policy.name}+cli")
+    return comm_policy
+
+
+def _n_devices(args) -> int:
+    return args.dp * args.tp * args.pp * args.cp * args.pod
+
+
+def main(argv=None):
+    ap = _parser()
+    args = ap.parse_args(argv)
+    runtime.force_cpu_devices(args.host_devices or _n_devices(args),
+                              asked=bool(args.host_devices))
+    try:
+        comm_policy_from_flags(args)
+    except ValueError as e:
+        ap.error(str(e))
+    runtime.use_compile_cache()
+    run(args)
+
+
+def run(args, cfg=None) -> dict:
+    """Train as the command line ``args`` say; return the per-step
+    ``loss``, ``grad_norm`` and ``step_time`` lists, the step's
+    ``compile_time`` (seconds), its ledger ``wire_bytes`` per device per
+    step for each parallelism dimension, and the final ``params`` and
+    ``opt_state``.
+
+    ``cfg`` replaces the ``--arch``/``--reduced``/``--layers`` config.
+    Each step time ends when the step's outputs are ready on the device;
+    the step is compiled ahead of the loop and its compile time is kept
+    apart."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro import configs
+    from repro.analysis import roofline
+    from repro.core import comms
+    from repro.data.pipeline import DataConfig, SyntheticCorpus
+    from repro.launch.mesh import make_mesh, parse_nodes_spec, validate_vpp
+    from repro.models.model import Model
+    from repro.models.params import MeshInfo
+    from repro.train import checkpoint, fault
+    from repro.train.optimizer import AdamConfig
+    from repro.train.train_step import (batch_specs, make_trainer,
+                                        zigzag_shard_seq)
+
+    if cfg is None:
+        cfg = configs.get(args.arch)
+        if args.reduced:
+            cfg = cfg.reduced()
+        if args.layers:
+            cfg = cfg.replace(n_layers=args.layers, groups=())
+    runtime.require_devices(_n_devices(args))
+    nodes = parse_nodes_spec(args.nodes, args.dp)
+    tp_nodes = parse_nodes_spec(args.tp_nodes, args.tp, flag="--tp-nodes")
+    pp_nodes = parse_nodes_spec(args.pp_nodes, args.pp, flag="--pp-nodes")
+    cp_nodes = parse_nodes_spec(args.cp_nodes, args.cp, flag="--cp-nodes")
+    mesh = make_mesh(args.dp, args.tp, args.pod, nodes=nodes,
+                     tp_nodes=tp_nodes, pp=args.pp, pp_nodes=pp_nodes,
+                     cp=args.cp, cp_nodes=cp_nodes)
+    mi = MeshInfo.from_mesh(mesh)
+    validate_vpp(args.vpp, args.pp, args.microbatches)
+    model = Model(cfg, mi, vpp=args.vpp)
+    comm_policy = comm_policy_from_flags(args)
 
     if args.policy_from:
         from repro.tune import policy_artifact
@@ -365,7 +425,9 @@ def main():
 
     def save_all(step, blocking):
         t1 = checkpoint.save(args.ckpt_dir, step, params, blocking=blocking)
-        t2 = checkpoint.save(opt_dir, step, ostate, blocking=blocking)
+        t2 = checkpoint.save(opt_dir, step, ostate,
+                             extra={"v_layout": trainer.opt.v_layout},
+                             blocking=blocking)
         t3 = checkpoint.save(codec_dir, step, cstate, blocking=blocking)
         ts = [t1, t2, t3]
         if args.tune:
@@ -440,20 +502,50 @@ def main():
         mon.tune_plan_hash = ctrl.plan().table_hash()
         mon.tune_decision_step = ctrl.last_decision_step
 
+    def put_batch(step):
+        np_batch = zigzag_shard_seq(data.batch(step), mi.cp)
+        return {k: jax.device_put(v, NamedSharding(mesh, bspecs[k]))
+                for k, v in np_batch.items()}
+
+    t0 = time.perf_counter()
+    with comms.record_traffic() as events:
+        if args.tune:
+            step_fn = trainer.step_tuned.lower(params, ostate, cstate, tstate,
+                                               put_batch(start)).compile()
+        else:
+            step_fn = trainer.step.lower(params, ostate, cstate,
+                                         put_batch(start)).compile()
+    hist = {"loss": [], "grad_norm": [], "step_time": [],
+            "compile_time": time.perf_counter() - t0,
+            "wire_bytes": roofline.ledger_summary(events,
+                                                  train=True)["per_dim"]}
+    print(f"compiled the step in {hist['compile_time']:.3f}s; wire bytes "
+          "per device per step: " + (", ".join(
+              f"{d}={b:.0f}" for d, b in sorted(hist["wire_bytes"].items()))
+              or "none"))
+    mem = step_fn.memory_analysis()
+    print(f"step memory per device (compiled): arguments "
+          f"{mem.argument_size_in_bytes}, outputs {mem.output_size_in_bytes}"
+          f", aliased {mem.alias_size_in_bytes}, temporaries "
+          f"{mem.temp_size_in_bytes} bytes")
+
     for step in range(start, start + args.steps):
         mon.begin()
-        np_batch = zigzag_shard_seq(data.batch(step), mi.cp)
-        batch = {k: jax.device_put(v, NamedSharding(mesh, bspecs[k]))
-                 for k, v in np_batch.items()}
+        batch = put_batch(step)
         if args.tune:
-            params, ostate, cstate, tstate, metrics = trainer.step_tuned(
+            params, ostate, cstate, tstate, metrics = step_fn(
                 params, ostate, cstate, tstate, batch)
         else:
-            params, ostate, cstate, metrics = trainer.step(params, ostate,
-                                                           cstate, batch)
+            params, ostate, cstate, metrics = step_fn(params, ostate,
+                                                      cstate, batch)
+        # the step ends when the device has finished it, not at dispatch
+        jax.block_until_ready((params, ostate, cstate, metrics))
         info = mon.end(step)
+        hist["loss"].append(float(metrics["loss"]))
+        hist["grad_norm"].append(float(metrics["grad_norm"]))
+        hist["step_time"].append(info["dt"])
         if args.tune:
-            ctrl.observe_loss(step, float(metrics["loss"]))
+            ctrl.observe_loss(step, hist["loss"][-1])
             if (step + 1 - start) % args.tune_interval == 0:
                 sigs, zeroed = trk.drain(tstate["sig"])
                 for d in ctrl.decide(step, sigs):
@@ -474,9 +566,9 @@ def main():
                         os.path.join(args.ckpt_dir, "tune_policy.json"),
                         ctrl)
         if step % 5 == 0 or step == start + args.steps - 1:
-            print(f"step {step:5d} loss={float(metrics['loss']):.4f} "
-                  f"gnorm={float(metrics['grad_norm']):.3f} "
-                  f"dt={info['dt']:.2f}s"
+            print(f"step {step:5d} loss={hist['loss'][-1]:.4f} "
+                  f"gnorm={hist['grad_norm'][-1]:.3f} "
+                  f"dt={info['dt']:.3f}s"
                   + (" STRAGGLER" if info["straggler"] else ""))
         if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
             save_all(step + 1, blocking=False)
@@ -494,9 +586,10 @@ def main():
                   f"({len(art['rules'])} site rules)")
         print("tuned codecs: " + ", ".join(
             f"{k}={v}" for k, v in sorted(ctrl.codec.items())))
-    print(f"done: final loss {float(metrics['loss']):.4f}, "
+    print(f"done: final loss {hist['loss'][-1]:.4f}, "
           f"teacher floor {data.optimal_xent():.4f}, "
           f"stragglers {mon.stragglers}/{mon.steps}")
+    return {**hist, "params": params, "opt_state": ostate}
 
 
 if __name__ == "__main__":

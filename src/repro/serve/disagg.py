@@ -116,7 +116,7 @@ class DisaggServer:
             tok, caches = self.srv.prefill_inner(params, sq)
             return jax.tree.map(lambda a: a[None], (tok, caches))
 
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(model.specs(), _lift_specs(bspecs)),
             out_specs=_lift_specs((tok_spec, cache_specs)),
@@ -147,8 +147,8 @@ class DisaggServer:
                 return jax.tree.map(hand, caches)
 
         lifted = _lift_specs(cspecs)
-        sm = compat.shard_map(fn, mesh=self.mesh, in_specs=(lifted,),
-                              out_specs=lifted, check_vma=False)
+        sm = jax.shard_map(fn, mesh=self.mesh, in_specs=(lifted,),
+                           out_specs=lifted, check_vma=False)
         return jax.jit(sm)
 
     def decode_step(self, B: int, s_max: int, s_enc: int = 0):
@@ -165,7 +165,7 @@ class DisaggServer:
             return jax.tree.map(lambda a: a[None], (tok, nc))
 
         lifted = _lift_specs(cspecs)
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             fn, mesh=self.mesh,
             in_specs=(model.specs(), _lift_specs(tok_spec), lifted, P()),
             out_specs=(P(POOL_AXIS, tok_spec[0]), lifted), check_vma=False)

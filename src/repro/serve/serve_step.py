@@ -104,7 +104,7 @@ class Server:
         tok_spec = P(None if (B == 1 or "data" in self.seq_axes)
                      else mi.batch_axes, None)
         out_tok_spec = P(tok_spec[0])
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             self.decode_inner, mesh=self.mesh,
             in_specs=(model.specs(), tok_spec, cspecs, P()),
             out_specs=(out_tok_spec, cspecs), check_vma=False)
@@ -114,7 +114,7 @@ class Server:
         model, mi, cfg = self.model, self.model.mi, self.model.cfg
         cache_specs = kv_cache.prefill_cache_specs(cfg, mi, B)
         tok_spec = P(mi.batch_axes if B > 1 else None)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             self.prefill_inner, mesh=self.mesh,
             in_specs=(model.specs(), bspecs),
             out_specs=(tok_spec, cache_specs), check_vma=False)
@@ -194,7 +194,7 @@ class PagedServer:
         structs, pspecs = paged_kv.pool_structs(
             cfg, mi, n_blocks, self.block_tokens, self.kv_codec)
         bs = mi.batch_axes if mi.dp > 1 else None
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             self.decode_inner, mesh=self.mesh,
             in_specs=(model.specs(), P(bs, None), pspecs, P(bs, None),
                       P(bs), P(bs)),

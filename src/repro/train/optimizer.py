@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -77,7 +78,7 @@ class AdamConfig:
     eps: float = 1e-8
     weight_decay: float = 0.0
     grad_clip: float = 1.0
-    state_bits: int = 32            # 8 -> bq8-quantized m/v (ZeRO-1 path)
+    state_bits: int = 32            # 8 -> bq8-quantized m/sqrt(v) (ZeRO-1)
     warmup: int = 10
     # > 1 splits the flat ZeRO-1 DP sync into that many contiguous bucket
     # slices, each with its own reduce-scatter (+ hier/pod psum) chain, and
@@ -122,6 +123,9 @@ class Adam:
     """Functional optimizer; init/apply run INSIDE shard_map."""
 
     def __init__(self, cfg: AdamConfig, mi: MeshInfo):
+        if cfg.state_bits == 8 and not cfg.b1 ** 2 < cfg.b2:
+            raise ValueError("8-bit optimizer state needs b1**2 < b2 "
+                             f"(got b1={cfg.b1}, b2={cfg.b2})")
         self.cfg = cfg
         self.mi = mi
 
@@ -202,6 +206,35 @@ class Adam:
         if self.cfg.state_bits == 8:
             return kops.bq_encode_blocks(x.reshape(-1, BLOCK), 8)
         return x
+
+    # At 8 bits v is kept as sqrt(v), which has m's dynamic range: bq8 of v
+    # itself rounds every entry below 1/254 of its block's largest to 0.
+    # Entries of sqrt(v) below that bound still round to 0, so the decode
+    # floors sqrt(v) at the least an exact Adam state with this m can hold:
+    # by Cauchy-Schwarz on the two moving averages,
+    #   |m| <= (1 - b1) / sqrt((1 - b2) (1 - b1**2 / b2)) * sqrt(v),
+    # which keeps the lane's update bounded.  32-bit state is v as is.
+    @property
+    def v_layout(self) -> str:
+        """What the ZeRO-1 ``v`` state holds; saved with checkpoints."""
+        return "sqrt_v" if self.cfg.state_bits == 8 else "v"
+
+    @property
+    def v_floor(self) -> float:
+        """Least sqrt(v) / |m| of an exact Adam state."""
+        c = self.cfg
+        return math.sqrt((1 - c.b2) * (1 - c.b1 ** 2 / c.b2)) / (1 - c.b1)
+
+    def _v_decode(self, s, m):
+        if self.cfg.state_bits != 8:
+            return s
+        r = self._state_decode(s)
+        return jnp.square(jnp.maximum(r, self.v_floor * jnp.abs(m)))
+
+    def _v_encode(self, v):
+        if self.cfg.state_bits != 8:
+            return v
+        return self._state_encode(jnp.sqrt(v))
 
     # ------------------------------------------------------------------
     def apply(self, params, grads, state):
@@ -354,7 +387,7 @@ class Adam:
         if bucketed:
             gchunk = gchunk * scale     # post-sync clip (see above)
         m = self._state_decode(state["m"])
-        v = self._state_decode(state["v"])
+        v = self._v_decode(state["v"], m)
         master, m, v = self._adam_update(gchunk, m, v, state["master"], step)
         # hpZ: master chunks are replicated per node, so this all-gather
         # rides only fast intra-node links
@@ -386,7 +419,7 @@ class Adam:
 
         new_params = jax.tree_util.tree_unflatten(treedef, new_leaves)
         new_state = {"fsdp": new_fsdp, "master": master,
-                     "m": self._state_encode(m), "v": self._state_encode(v),
+                     "m": self._state_encode(m), "v": self._v_encode(v),
                      "step": step + 1}
         return new_params, new_state, {"grad_norm": gnorm,
                                        "lr": _lr_at(cfg, step)}

@@ -134,17 +134,11 @@ def parse_remat_policy(spec, vpp: int):
 
 def _remat_wrap(fn, offload: bool):
     """``jax.checkpoint`` around a (virtual-)stage body.  ``offload``
-    additionally parks matmul residuals in pinned host memory; backends
-    without host offload fall back LOUDLY to plain checkpointing."""
+    additionally parks matmul residuals in pinned host memory."""
     if offload:
-        try:
-            pol = jax.checkpoint_policies.offload_dot_with_no_batch_dims(
-                "device", "pinned_host")
-            return jax.checkpoint(fn, policy=pol)
-        except Exception as e:  # pragma: no cover - backend-dependent
-            print("WARNING: activation-stash offload unavailable "
-                  f"({type(e).__name__}: {e}) — falling back to plain "
-                  "jax.checkpoint")
+        pol = jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+            "device", "pinned_host")
+        return jax.checkpoint(fn, policy=pol)
     return jax.checkpoint(fn)
 
 

@@ -434,15 +434,15 @@ class Trainer:
             with comms.vma_mode(False):
                 return opt.init(params)
 
-        self.opt_init = jax.jit(compat.shard_map(
+        self.opt_init = jax.jit(jax.shard_map(
             opt_init_fn, mesh=self.mesh, in_specs=(pspecs,),
             out_specs=ospecs, check_vma=False))
         self.step = jax.jit(
-            compat.shard_map(step_fn, mesh=self.mesh,
-                             in_specs=(pspecs, ospecs, cspecs, bspecs),
-                             out_specs=(pspecs, ospecs, cspecs,
-                                        METRIC_SPECS),
-                             check_vma=False),
+            jax.shard_map(step_fn, mesh=self.mesh,
+                          in_specs=(pspecs, ospecs, cspecs, bspecs),
+                          out_specs=(pspecs, ospecs, cspecs,
+                                     METRIC_SPECS),
+                          check_vma=False),
             donate_argnums=(0, 1, 2))
 
         if self.tune:
@@ -471,12 +471,12 @@ class Trainer:
             # tune_state is NOT donated: the host re-feeds the same select
             # scalars every step and drains sig on the controller cadence
             self.step_tuned = jax.jit(
-                compat.shard_map(step_tuned_fn, mesh=self.mesh,
-                                 in_specs=(pspecs, ospecs, cspecs, tspecs,
-                                           bspecs),
-                                 out_specs=(pspecs, ospecs, cspecs, tspecs,
-                                            METRIC_SPECS),
-                                 check_vma=False),
+                jax.shard_map(step_tuned_fn, mesh=self.mesh,
+                              in_specs=(pspecs, ospecs, cspecs, tspecs,
+                                        bspecs),
+                              out_specs=(pspecs, ospecs, cspecs, tspecs,
+                                         METRIC_SPECS),
+                              check_vma=False),
                 donate_argnums=(0, 1, 2))
 
     def init_all(self, key):

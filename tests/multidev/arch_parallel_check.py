@@ -36,10 +36,10 @@ def loss_on_mesh(cfg, shape, scheme, batch_and_specs, params_src=None):
     batch, bspecs = batch_and_specs
     def step(params, batch):
         return m.loss_fn(params, batch)
-    sm = jax.jit(compat.shard_map(step, mesh=mesh,
-                                  in_specs=(m.specs(), bspecs),
-                                  out_specs=(P(), {"xent": P(), "tokens": P()}),
-                                  check_vma=True))
+    sm = jax.jit(jax.shard_map(step, mesh=mesh,
+                               in_specs=(m.specs(), bspecs),
+                               out_specs=(P(), {"xent": P(), "tokens": P()}),
+                               check_vma=True))
     with schemes.use(scheme):
         loss, met = sm(params, batch)
     return float(loss)

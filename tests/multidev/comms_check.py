@@ -8,8 +8,8 @@ mesh = compat.make_mesh((8,), ("x",))
 rng = np.random.default_rng(0)
 
 def smap(f, in_specs, out_specs):
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs, check_vma=True))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=True))
 
 x = jnp.asarray(rng.normal(size=(8, 4, 256)).astype(np.float32))  # leading dim -> devices
 

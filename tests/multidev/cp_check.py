@@ -59,7 +59,7 @@ def ring_sharded(causal, window, k_valid):
         with schemes.use("baseline"), comms.vma_mode(False):
             return ring_attention(q, k, v, pos, pos, mi, causal, window,
                                   k_valid=vl if k_valid else None)
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(QS, QS, QS, PS, PS), out_specs=QS,
         check_vma=False))
     # zigzag host permutation, then contiguous cp sharding — rank i holds
@@ -135,8 +135,8 @@ def fwd(p, b):
         return model.loss_fn(p, b)[0]
 
 
-sm = jax.jit(compat.shard_map(fwd, mesh=mesh, in_specs=(pspecs, bspecs),
-                              out_specs=P(), check_vma=False))
+sm = jax.jit(jax.shard_map(fwd, mesh=mesh, in_specs=(pspecs, bspecs),
+                           out_specs=P(), check_vma=False))
 shapes = jax.eval_shape(model.init, jax.random.key(0))
 bshapes = {kk: jax.ShapeDtypeStruct((8, 16), jnp.int32)
            for kk in ("tokens", "labels")}
@@ -160,7 +160,7 @@ RING = [(j, (j + 1) % 4) for j in range(4)]
 
 
 def trace_ring(scheme):
-    smh = jax.jit(compat.shard_map(
+    smh = jax.jit(jax.shard_map(
         lambda a: comms.ppermute(a, CPAX, RING, comms.site("cp", "ring_kv")),
         mesh=hmesh, in_specs=(P("data"),), out_specs=P("data"),
         check_vma=False))

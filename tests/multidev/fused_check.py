@@ -68,7 +68,7 @@ def threepass_codecs():
 
 
 def run(fn, x):
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         fn, mesh=mesh, in_specs=(P("data", "stage", "model"),),
         out_specs=P("data", "stage", "model"), check_vma=False))
     return np.asarray(jax.block_until_ready(sm(x)))

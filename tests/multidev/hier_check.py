@@ -27,8 +27,8 @@ rng = np.random.default_rng(0)
 
 
 def smap(f, in_specs, out_specs):
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs, check_vma=False))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def ints(shape):

@@ -45,8 +45,8 @@ def run_one(mesh, axis, fn, shape):
     recorded (analytic events, wire events)."""
     spec = P(*mesh.axis_names)
     x = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-    sm = jax.jit(compat.shard_map(fn, mesh=mesh, in_specs=(spec,),
-                                  out_specs=spec, check_vma=False))
+    sm = jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(spec,),
+                               out_specs=spec, check_vma=False))
     with comms.record_traffic() as events:
         jax.block_until_ready(sm(x))
     return list(events), list(events.wire)

@@ -79,7 +79,7 @@ def grads_of(loss_fn):
         with schemes.use("baseline"), comms.vma_mode(False):
             (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(p, b)
         return loss, g
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         f, mesh=mesh, in_specs=(pspecs, bspecs), out_specs=(P(), pspecs),
         check_vma=False))
     loss, g = sm(params, batch)
@@ -105,7 +105,7 @@ hmesh = compat.make_mesh((2, 2, 2), ("data", "ppnode", "stage"))
 
 def trace_handoff(scheme, hier):
     axis = PPN if hier else JOINT
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         lambda a: comms.stage_send(a, axis), mesh=hmesh,
         in_specs=(P("data"),), out_specs=P("data"), check_vma=False))
     with schemes.use(scheme), comms.record_traffic() as events:
@@ -134,8 +134,8 @@ SPEC = P(("data", "ppnode", "stage"))
 
 
 def smap(f):
-    return jax.jit(compat.shard_map(f, mesh=hmesh, in_specs=(SPEC,),
-                                    out_specs=SPEC, check_vma=False))
+    return jax.jit(jax.shard_map(f, mesh=hmesh, in_specs=(SPEC,),
+                                 out_specs=SPEC, check_vma=False))
 
 
 with schemes.use("baseline"):

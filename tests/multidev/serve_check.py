@@ -41,7 +41,7 @@ def run_arch(arch, S=16, B=4, n_new=4, s_max=32):
             with schemes.use("baseline"):
                 logits, _, _ = model.forward(p, bb, phase="train")
             return logits  # [B, S_full, V_loc] on each model shard
-        sm = jax.jit(compat.shard_map(f, mesh=mesh,
+        sm = jax.jit(jax.shard_map(f, mesh=mesh,
                      in_specs=(model.specs(), {k: bspecs[k] for k in b2}),
                      out_specs=P("data", None, "model"), check_vma=False))
         return np.asarray(sm(params, b2))  # [B, S_full, V]

@@ -32,8 +32,8 @@ rng = np.random.default_rng(0)
 
 
 def smap(f):
-    return jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(SPEC,),
-                                    out_specs=SPEC, check_vma=False))
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(SPEC,),
+                                 out_specs=SPEC, check_vma=False))
 
 
 def ints(shape):
@@ -89,7 +89,7 @@ def loss_on(mesh_, cfg, batch):
     m = Model(cfg, mi)
     params = m.init(jax.random.key(1))
     bspecs = {"tokens": P("data", None), "labels": P("data", None)}
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         lambda p, b: m.loss_fn(p, b), mesh=mesh_,
         in_specs=(m.specs(), bspecs),
         out_specs=(P(), {"xent": P(), "tokens": P()}), check_vma=True))

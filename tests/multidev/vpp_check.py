@@ -99,7 +99,7 @@ def grads_of(loss_fn):
         with schemes.use("baseline"), comms.vma_mode(False):
             (loss, _), g = jax.value_and_grad(loss_fn, has_aux=True)(p, b)
         return loss, g
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         f, mesh=rmesh, in_specs=(rpspecs, rbspecs),
         out_specs=(P(), rpspecs), check_vma=False))
     loss, g = sm(rparams, rbatch)
@@ -155,7 +155,7 @@ def trace_pipeline(vpp, scheme_name):
         with schemes.use(scheme_name), comms.vma_mode(False):
             return lf(p, b)[0]
 
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         f, mesh=hmesh, in_specs=(model.specs(), bspecs), out_specs=P(),
         check_vma=False))
     bstructs = {"tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32),
@@ -203,8 +203,8 @@ SPEC = P(("data", "stage"))
 
 
 def smap(f):
-    return jax.jit(compat.shard_map(f, mesh=ring_mesh, in_specs=(SPEC,),
-                                    out_specs=SPEC, check_vma=False))
+    return jax.jit(jax.shard_map(f, mesh=ring_mesh, in_specs=(SPEC,),
+                                 out_specs=SPEC, check_vma=False))
 
 
 with schemes.use("baseline"):

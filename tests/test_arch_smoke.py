@@ -67,7 +67,7 @@ def test_reduced_forward_and_grad(arch):
                           ("data", "model"))
         return loss, met["xent"], gn
 
-    sm = jax.jit(compat.shard_map(
+    sm = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(model.specs(), bspecs),
         out_specs=(P(), P(), P())))
     with schemes.use("baseline"):

@@ -204,3 +204,43 @@ def test_restore_codec_happy_path(trainer, state, mesh, tmp_path, capsys):
     assert "restored codec state at step 9" in out
     assert "WARNING" not in out
     assert_tree_equal(got, cstate)
+
+
+# ---- the v layout tag ------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def trainer8(mesh):
+    from repro.train.optimizer import AdamConfig
+    return Trainer(Model(CFG, MeshInfo.from_mesh(mesh)), mesh, scheme=EF,
+                   opt_cfg=AdamConfig(state_bits=8))
+
+
+def test_restore_opt_refuses_8bit_v_saved_before_the_tag(trainer8, mesh,
+                                                         tmp_path, capsys):
+    """8-bit state saved with v, not sqrt(v), and so with no tag, is not
+    read as sqrt(v): the moments restart, loudly.  A tagged save comes
+    back as saved."""
+    params, ostate, _ = trainer8.init_all(jax.random.key(0))
+    odir = str(tmp_path / "opt")
+    checkpoint.save(odir, 3, ostate)
+    got = _restore_opt(trainer8, params, odir, 3, mesh, checkpoint)
+    out = capsys.readouterr().out
+    assert "WARNING: optimizer state not portable" in out
+    assert "its v is saved as 'v', this run keeps 'sqrt_v'" in out
+    assert_tree_equal(got, trainer8.opt_init(params))
+
+    checkpoint.save(odir, 4, ostate, extra={"v_layout": "sqrt_v"})
+    again = _restore_opt(trainer8, params, odir, 4, mesh, checkpoint)
+    assert "WARNING" not in capsys.readouterr().out
+    assert_tree_equal(again, ostate)
+
+
+def test_restore_opt_refuses_a_v_layout_it_cannot_read(trainer, state, mesh,
+                                                       tmp_path, capsys):
+    params, ostate, _ = state
+    odir = str(tmp_path / "opt")
+    checkpoint.save(odir, 2, ostate, extra={"v_layout": "sqrt_v"})
+    got = _restore_opt(trainer, params, odir, 2, mesh, checkpoint)
+    out = capsys.readouterr().out
+    assert "WARNING: optimizer state not portable to this topology" in out
+    assert_tree_equal(got, trainer.opt_init(params))

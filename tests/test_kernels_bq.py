@@ -10,6 +10,7 @@ Contract asserted here:
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
@@ -82,6 +83,31 @@ def test_fused_wire_only_and_decode_add_match_full(bits):
         s_only = ops.bq_decode_add_blocks(w, loc, bits, backend=be)
         np.testing.assert_array_equal(np.asarray(s_full),
                                       np.asarray(s_only))
+
+
+@pytest.mark.parametrize("bits", BITS)
+def test_block_ops_take_leading_dims(bits):
+    """A gathered wire is ``[n, M, 128]``: the kernels see its shards as
+    rows of one matrix and give what the oracles give, shard for shard."""
+    x = jnp.asarray(_rand((3, 16, 128), np.float32, seed=7))
+    loc = jnp.asarray(_rand((3, 16, 128), np.float32, seed=8))
+    want_w = ops.bq_encode_blocks(x, bits, backend="jnp")
+    got_w = ops.bq_encode_blocks(x, bits, backend="pallas_interpret")
+    cases = [(got_w, want_w),
+             (ops.bq_decode_blocks(want_w, bits, backend="pallas_interpret"),
+              ops.bq_decode_blocks(want_w, bits, backend="jnp")),
+             (ops.bq_decode_add_encode_blocks(want_w, loc, bits,
+                                              backend="pallas_interpret"),
+              ops.bq_decode_add_encode_blocks(want_w, loc, bits,
+                                              backend="jnp")),
+             (ops.bq_decode_add_blocks(want_w, loc, bits,
+                                       backend="pallas_interpret"),
+              ops.bq_decode_add_blocks(want_w, loc, bits, backend="jnp"))]
+    for got, want in cases:
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                        strict=True):
+            assert g.shape == w.shape and g.shape[0] == 3
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 @pytest.mark.parametrize("bits", BITS)

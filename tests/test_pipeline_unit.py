@@ -278,7 +278,7 @@ def test_microbatch_grad_accum_encoder_and_vision(arch):
         def f(p, b):
             with schemes.use("baseline"), comms.vma_mode(False):
                 return loss_fn(p, b)[0]
-        sm = jax.jit(compat.shard_map(
+        sm = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(model.specs(), bspecs), out_specs=P(),
             check_vma=False))
         return float(sm(params, batch))

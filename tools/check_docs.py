@@ -111,6 +111,8 @@ def defined_flags() -> set[str]:
             if any(part in SKIP_DIRS for part in p.parts):
                 continue
             out |= set(_ADD_ARG_RE.findall(p.read_text(encoding="utf-8")))
+    for p in sorted(ROOT.glob("*.py")):          # chip_smoke.py
+        out |= set(_ADD_ARG_RE.findall(p.read_text(encoding="utf-8")))
     return out
 
 
