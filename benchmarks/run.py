@@ -5,7 +5,6 @@ Prints ``name,us_per_call,derived`` CSV rows.
   bench_codec        ZFP-rate trade-off microbench        (paper §II-A/IV-C)
   bench_collectives  wire bytes per parallelism dim/scheme (paper Fig 1, §III)
   bench_convergence  loss curves per scheme               (paper Figs 7c-11)
-  bench_throughput   modeled throughput uplift            (paper Figs 7a-10b)
   bench_step_time    measured fused vs three-pass wall time (paper §IV-A)
   bench_serve        serving: prefill/decode rates, continuous batching,
                      at-rest KV codec cost + capacity
@@ -32,7 +31,7 @@ import sys           # noqa: E402
 import time          # noqa: E402
 
 MODULES = ("bench_codec", "bench_collectives", "bench_convergence",
-           "bench_throughput", "bench_step_time", "bench_serve")
+           "bench_step_time", "bench_serve")
 
 
 def main() -> None:

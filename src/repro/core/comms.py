@@ -65,6 +65,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import codecs, compat, policy
 from repro.kernels import ops
 from repro.kernels.ref import BLOCK
@@ -270,19 +271,25 @@ class _wire_site:
     """Best-effort site tag for the measured wire events: the public
     wrappers bind their site's ledger tag around the (eagerly traced)
     forward impl, so ``_log`` can attribute hops to a site.  Backward
-    impls trace later, outside any binding, and fall back to "-"."""
+    impls trace later, outside any binding, and fall back to "-".
+
+    It also enters the site's named scope (``obs.comms_scope``), which the
+    compiled step keeps on the forward impl's operations and, through the
+    transpose, on the backward impl's."""
 
     def __init__(self, tag: str):
         self.tag = tag
+        self.scope = jax.named_scope(obs.comms_scope(tag))
 
     def __enter__(self):
         self.prev = getattr(_rec, "wire_tag", "-")
         _rec.wire_tag = self.tag
+        self.scope.__enter__()
         return self
 
     def __exit__(self, *exc):
         _rec.wire_tag = self.prev
-        return False
+        return self.scope.__exit__(*exc)
 
 
 class ring_options:

@@ -52,7 +52,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import time
 
 from repro.launch import runtime
 
@@ -340,19 +339,22 @@ def main(argv=None):
 def run(args, cfg=None) -> dict:
     """Train as the command line ``args`` say; return the per-step
     ``loss``, ``grad_norm`` and ``step_time`` lists, the step's
-    ``compile_time`` (seconds), its ledger ``wire_bytes`` per device per
-    step for each parallelism dimension, and the final ``params`` and
-    ``opt_state``.
+    ``compile_time`` (seconds of tracing, lowering, compiling and cache
+    loading on ``repro.obs``'s compile clock), its ledger ``wire_bytes``
+    per device per step for each parallelism dimension, and the final
+    ``params`` and ``opt_state``.
 
     ``cfg`` replaces the ``--arch``/``--reduced``/``--layers`` config.
     Each step time ends when the step's outputs are ready on the device;
     the step is compiled ahead of the loop and its compile time is kept
-    apart."""
+    apart.  Under the profiler each step is a ``train`` step annotation,
+    with host spans ``repro.batch``, ``repro.sync``, ``repro.tune`` and
+    ``repro.checkpoint`` inside it."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec
 
-    from repro import configs
+    from repro import configs, obs
     from repro.analysis import roofline
     from repro.core import comms
     from repro.data.pipeline import DataConfig, SyntheticCorpus
@@ -507,7 +509,7 @@ def run(args, cfg=None) -> dict:
         return {k: jax.device_put(v, NamedSharding(mesh, bspecs[k]))
                 for k, v in np_batch.items()}
 
-    t0 = time.perf_counter()
+    c0 = obs.snapshot()
     with comms.record_traffic() as events:
         if args.tune:
             step_fn = trainer.step_tuned.lower(params, ostate, cstate, tstate,
@@ -515,11 +517,12 @@ def run(args, cfg=None) -> dict:
         else:
             step_fn = trainer.step.lower(params, ostate, cstate,
                                          put_batch(start)).compile()
+    c1 = obs.snapshot()
     hist = {"loss": [], "grad_norm": [], "step_time": [],
-            "compile_time": time.perf_counter() - t0,
+            "compile_time": c1["total_s"] - c0["total_s"],
             "wire_bytes": roofline.ledger_summary(events,
                                                   train=True)["per_dim"]}
-    print(f"compiled the step in {hist['compile_time']:.3f}s; wire bytes "
+    print(f"compiled the step in {obs.split(c0, c1)}; wire bytes "
           "per device per step: " + (", ".join(
               f"{d}={b:.0f}" for d, b in sorted(hist["wire_bytes"].items()))
               or "none"))
@@ -530,53 +533,60 @@ def run(args, cfg=None) -> dict:
           f"{mem.temp_size_in_bytes} bytes")
 
     for step in range(start, start + args.steps):
-        mon.begin()
-        batch = put_batch(step)
-        if args.tune:
-            params, ostate, cstate, tstate, metrics = step_fn(
-                params, ostate, cstate, tstate, batch)
-        else:
-            params, ostate, cstate, metrics = step_fn(params, ostate,
-                                                      cstate, batch)
-        # the step ends when the device has finished it, not at dispatch
-        jax.block_until_ready((params, ostate, cstate, metrics))
-        info = mon.end(step)
-        hist["loss"].append(float(metrics["loss"]))
-        hist["grad_norm"].append(float(metrics["grad_norm"]))
-        hist["step_time"].append(info["dt"])
-        if args.tune:
-            ctrl.observe_loss(step, hist["loss"][-1])
-            if (step + 1 - start) % args.tune_interval == 0:
-                sigs, zeroed = trk.drain(tstate["sig"])
-                for d in ctrl.decide(step, sigs):
-                    if d.changed:
-                        print(f"tune[{d.site}] step {step}: {d.action} "
-                              f"{d.from_codec} -> {d.to_codec} "
-                              f"({d.reason})")
-                rep = NamedSharding(mesh, PartitionSpec())
-                tstate = {
-                    "select": {k: jax.device_put(jnp.int32(v), rep)
-                               for k, v in ctrl.select_indices().items()},
-                    "sig": {k: jax.device_put(jnp.asarray(z), rep)
-                            for k, z in zeroed.items()}}
-                mon.tune_plan_hash = ctrl.plan().table_hash()
-                mon.tune_decision_step = step
-                if args.ckpt_dir:
-                    policy_artifact.emit(
-                        os.path.join(args.ckpt_dir, "tune_policy.json"),
-                        ctrl)
-        if step % 5 == 0 or step == start + args.steps - 1:
-            print(f"step {step:5d} loss={hist['loss'][-1]:.4f} "
-                  f"gnorm={hist['grad_norm'][-1]:.3f} "
-                  f"dt={info['dt']:.3f}s"
-                  + (" STRAGGLER" if info["straggler"] else ""))
-        if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
-            save_all(step + 1, blocking=False)
+        with jax.profiler.StepTraceAnnotation("train", step_num=step):
+            mon.begin()
+            with obs.span(obs.BATCH):
+                batch = put_batch(step)
+            if args.tune:
+                params, ostate, cstate, tstate, metrics = step_fn(
+                    params, ostate, cstate, tstate, batch)
+            else:
+                params, ostate, cstate, metrics = step_fn(params, ostate,
+                                                          cstate, batch)
+            # the step ends when the device has finished it, not at dispatch
+            with obs.span(obs.SYNC):
+                jax.block_until_ready((params, ostate, cstate, metrics))
+            info = mon.end(step)
+            hist["loss"].append(float(metrics["loss"]))
+            hist["grad_norm"].append(float(metrics["grad_norm"]))
+            hist["step_time"].append(info["dt"])
+            if args.tune:
+                ctrl.observe_loss(step, hist["loss"][-1])
+            if args.tune and (step + 1 - start) % args.tune_interval == 0:
+                with obs.span(obs.TUNE):
+                    sigs, zeroed = trk.drain(tstate["sig"])
+                    for d in ctrl.decide(step, sigs):
+                        if d.changed:
+                            print(f"tune[{d.site}] step {step}: {d.action} "
+                                  f"{d.from_codec} -> {d.to_codec} "
+                                  f"({d.reason})")
+                    rep = NamedSharding(mesh, PartitionSpec())
+                    tstate = {
+                        "select": {k: jax.device_put(jnp.int32(v), rep)
+                                   for k, v in
+                                   ctrl.select_indices().items()},
+                        "sig": {k: jax.device_put(jnp.asarray(z), rep)
+                                for k, z in zeroed.items()}}
+                    mon.tune_plan_hash = ctrl.plan().table_hash()
+                    mon.tune_decision_step = step
+                    if args.ckpt_dir:
+                        policy_artifact.emit(
+                            os.path.join(args.ckpt_dir, "tune_policy.json"),
+                            ctrl)
+            if step % 5 == 0 or step == start + args.steps - 1:
+                print(f"step {step:5d} loss={hist['loss'][-1]:.4f} "
+                      f"gnorm={hist['grad_norm'][-1]:.3f} "
+                      f"dt={info['dt']:.3f}s"
+                      + (" STRAGGLER" if info["straggler"] else ""))
+            if args.ckpt_dir and (step + 1) % args.ckpt_every == 0:
+                with obs.span(obs.CHECKPOINT):
+                    save_all(step + 1, blocking=False)
     if args.ckpt_dir:
         for t in pending:
             t.join()
         if checkpoint.latest_step(args.ckpt_dir) != start + args.steps:
-            save_all(start + args.steps, blocking=True)
+            with obs.span(obs.CHECKPOINT):
+                save_all(start + args.steps, blocking=True)
         print(f"checkpointed at step {start + args.steps}")
     if args.tune:
         if args.ckpt_dir:

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import comms, compat
 from repro.models import layers
 from repro.models.params import D as Dd, MeshInfo
@@ -237,6 +238,7 @@ def _theta(cfg, window):
 # entry points
 # --------------------------------------------------------------------------
 
+@jax.named_scope(obs.ATTENTION)
 def attn_train(p, x, pos, cfg, mi: MeshInfo, mode: str, causal=True, window=0,
                cross=None, cross_pos=None, pos3=None, want_cache=False):
     """Training/prefill attention sublayer.
@@ -296,6 +298,7 @@ def _gather_pos(pos, mi):
         if mi.tp > 1 else pos
 
 
+@jax.named_scope(obs.ATTENTION)
 def attn_decode(p, x, cache, index, cfg, mi: MeshInfo, mode: str, window=0,
                 seq_axes=("model",), pos3=None, cross: bool = False):
     """Single-token decode.
@@ -353,6 +356,7 @@ def attn_decode(p, x, cache, index, cfg, mi: MeshInfo, mode: str, window=0,
     return y, ({**cache, "k": k, "v": v} if not cross else cache)
 
 
+@jax.named_scope(obs.ATTENTION)
 def attn_decode_paged(p, x, pool, tables, pos, active, cfg, mi: MeshInfo,
                       *, bits, block_tokens, window=0, pos3=None,
                       backend=None):

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import comms, compat
 from repro.models.params import Pv, fsdp_dim, MeshInfo
 
@@ -53,6 +54,7 @@ def layer_norm(x, gain, bias, eps):
     return (y * gain.astype(_F32) + bias.astype(_F32)).astype(x.dtype)
 
 
+@jax.named_scope(obs.NORM)
 def norm(p, x, cfg, mi):
     if cfg.norm == "ln":
         return layer_norm(x, use(p["g"], mi), use(p["b"], mi), cfg.norm_eps)
@@ -119,6 +121,7 @@ def embed_plan(cfg):
                         dtype=cfg.dtype)}
 
 
+@jax.named_scope(obs.EMBED)
 def embed(p, tokens, cfg, mi, sp: bool = True):
     """Vocab-parallel embedding (Megatron-SP form).
 
@@ -144,6 +147,7 @@ def embed(p, tokens, cfg, mi, sp: bool = True):
     return e
 
 
+@jax.named_scope(obs.HEAD)
 def lm_head_logits(params, x, cfg, mi, sp: bool = True):
     """x [B, S_loc, D] -> vocab-sharded logits [B, S, V_loc] (f32).
 
@@ -167,6 +171,7 @@ def lm_head_plan(cfg):
                                 spec=(None, "model"), dtype=cfg.dtype)}}
 
 
+@jax.named_scope(obs.HEAD)
 def vocab_parallel_xent(logits, labels, cfg, mi):
     """Vocab-sharded cross-entropy.
 
@@ -227,6 +232,7 @@ def _act(h, kind):
     raise ValueError(kind)
 
 
+@jax.named_scope(obs.MLP)
 def mlp(p, x, cfg, mi, sp: bool = True):
     """Column->row parallel MLP.
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import comms
 from repro.models import layers
 from repro.models.params import D as Dd, MeshInfo
@@ -54,6 +55,7 @@ def capacity(cfg, tokens: int) -> int:
     return max(4, -(-c // 4) * 4)
 
 
+@jax.named_scope(obs.MOE)
 def moe_block(p, x, cfg, mi: MeshInfo, sp: bool = True):
     """x [B, S_loc, D] -> (y [B, S_loc, D], aux dict)."""
     if cfg.moe_ws and not sp and mi.dp > 1:

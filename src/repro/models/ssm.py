@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import comms, compat
 from repro.models import layers
 from repro.models.params import D as Dd, MeshInfo
@@ -153,6 +154,7 @@ def _causal_conv(xi, w, b, prev):
     return y + b
 
 
+@jax.named_scope(obs.SSM)
 def mamba_block(p, x, cfg, mi: MeshInfo, sp: bool = True,
                 want_cache: bool = False):
     """x [B, S_loc, D] -> [B, S_loc, D].  Seq sharded over model when sp.
@@ -241,6 +243,7 @@ def _broadcast_final(incl, tail, mi: MeshInfo, sp: bool):
 # decode (single token): channel-sharded over model via weight slicing
 # --------------------------------------------------------------------------
 
+@jax.named_scope(obs.SSM)
 def mamba_decode(p, x, cache, cfg, mi: MeshInfo):
     """x [B, 1, D]; cache {conv: [B,K-1,di_loc], state: [B,H_loc,P,N]}.
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import comms, compat
 from repro.models.params import D as Dd, MeshInfo
 from repro.models.layers import use
@@ -52,6 +53,7 @@ def mlstm_plan(cfg):
     }
 
 
+@jax.named_scope(obs.XLSTM)
 def mlstm_block(p, x, cfg, mi: MeshInfo, sp: bool = True,
                 want_cache: bool = False):
     """x [B, S_loc, D] -> [B, S_loc, D] (+ decode-layout state cache)."""
@@ -108,6 +110,7 @@ def mlstm_block(p, x, cfg, mi: MeshInfo, sp: bool = True,
     return out, {"C": C_tot, "n": n_tot[:, :, 0, :]}
 
 
+@jax.named_scope(obs.XLSTM)
 def mlstm_decode(p, x, cache, cfg, mi: MeshInfo):
     """Single token; matrix state sharded over model on the value dim.
 
@@ -215,6 +218,7 @@ def _slstm_scan(p, x, cfg, mi, h0=None, c0=None, n0=None, m0=None):
     return y, (h, c, n, m)
 
 
+@jax.named_scope(obs.XLSTM)
 def slstm_block(p, x, cfg, mi: MeshInfo, sp: bool = True,
                 want_cache: bool = False):
     """x [B, S_loc, D] -> [B, S_loc, D] under sequence sharding.
@@ -251,6 +255,7 @@ def slstm_block(p, x, cfg, mi: MeshInfo, sp: bool = True,
     return out, {"h": h, "c": c, "n": n, "m": m}
 
 
+@jax.named_scope(obs.XLSTM)
 def slstm_decode(p, x, cache, cfg, mi: MeshInfo):
     """Single step; state replicated (sLSTM state is small)."""
     y, (h, c, n, m) = _slstm_scan(p, x, cfg, mi, cache["h"], cache["c"],

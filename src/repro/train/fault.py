@@ -5,7 +5,9 @@ training loop; here the same logic is exercised single-process (tests
 simulate failures by killing/restarting the loop).
 
 * StepMonitor  — EMA of step wall-time; flags stragglers (step > k x EMA)
-  and writes a heartbeat file other hosts / the launcher can watch.
+  and writes a heartbeat file other hosts / the launcher can watch, with
+  the process's count of compiled executables (a step that recompiled
+  raises it).
 * RestartPolicy — decides recovery actions: resume from the latest
   checkpoint (deterministic data stream makes the replay exact), and
   supports *elastic* restarts onto a smaller/larger mesh via
@@ -19,6 +21,8 @@ import json
 import os
 import pathlib
 import time
+
+from repro import obs
 
 
 @dataclasses.dataclass
@@ -53,7 +57,8 @@ class StepMonitor:
             self.ema_decay * self.ema + (1 - self.ema_decay) * dt
         if self.heartbeat_path:
             hb = {"step": step, "t": time.time(), "dt": dt,
-                  "ema": self.ema, "straggler": is_straggler}
+                  "ema": self.ema, "straggler": is_straggler,
+                  "compiles": obs.compile_count()}
             if self.tune_plan_hash is not None:
                 hb["tune_plan_hash"] = self.tune_plan_hash
                 hb["tune_decision_step"] = self.tune_decision_step

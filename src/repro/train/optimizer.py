@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.core import comms
 from repro.kernels import ops as kops
 from repro.kernels.ref import BLOCK
@@ -185,6 +186,7 @@ class Adam:
         return sum(l.v.size for l, c in zip(leaves, classes) if c != "A")
 
     # ------------------------------------------------------------------
+    @jax.named_scope(obs.UPDATE)
     def _adam_update(self, g, m, v, master, step):
         c = self.cfg
         m = c.b1 * m + (1 - c.b1) * g
@@ -197,11 +199,13 @@ class Adam:
             upd = upd + c.weight_decay * master
         return master - _lr_at(c, step) * upd, m, v
 
+    @jax.named_scope(obs.STATE_CODEC)
     def _state_decode(self, s):
         if self.cfg.state_bits == 8:
             return kops.bq_decode_blocks(s, 8).reshape(-1)
         return s
 
+    @jax.named_scope(obs.STATE_CODEC)
     def _state_encode(self, x):
         if self.cfg.state_bits == 8:
             return kops.bq_encode_blocks(x.reshape(-1, BLOCK), 8)
@@ -225,18 +229,21 @@ class Adam:
         c = self.cfg
         return math.sqrt((1 - c.b2) * (1 - c.b1 ** 2 / c.b2)) / (1 - c.b1)
 
+    @jax.named_scope(obs.STATE_CODEC)
     def _v_decode(self, s, m):
         if self.cfg.state_bits != 8:
             return s
         r = self._state_decode(s)
         return jnp.square(jnp.maximum(r, self.v_floor * jnp.abs(m)))
 
+    @jax.named_scope(obs.STATE_CODEC)
     def _v_encode(self, v):
         if self.cfg.state_bits != 8:
             return v
         return self._state_encode(jnp.sqrt(v))
 
     # ------------------------------------------------------------------
+    @jax.named_scope(obs.OPTIMIZER)
     def apply(self, params, grads, state):
         """Returns (new_params, new_state, stats).  Inside shard_map."""
         mi, cfg = self.mi, self.cfg
@@ -313,17 +320,21 @@ class Adam:
         rep = {"A": pod * node * cpr,
                "B": mi.dp * pod * node * cpr,
                "C": mi.dp * mi.tp * pod * node * cpr}
-        sq = jnp.float32(0.0)
-        for g, c in zip(gleaves, classes):
-            # stage-sharded leaves are distinct per stage rank (counted
-            # once by the psum over all axes); stage-replicated leaves were
-            # just folded over the stage axis, so divide their square out
-            r = rep[c] * (mi.pp if mi.pp > 1 and "stage" not in g.spec else 1)
-            sq = sq + jnp.sum(g.v.astype(_F32) ** 2) / r
-        sq = comms.varying_all(sq, mi.all_axes)
-        sq = lax.psum(sq, mi.all_axes)
-        gnorm = jnp.sqrt(sq)
-        scale = jnp.minimum(1.0, cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
+        with jax.named_scope(obs.CLIP):
+            sq = jnp.float32(0.0)
+            for g, c in zip(gleaves, classes):
+                # stage-sharded leaves are distinct per stage rank
+                # (counted once by the psum over all axes);
+                # stage-replicated leaves were just folded over the stage
+                # axis, so divide their square out
+                r = rep[c] * (mi.pp if mi.pp > 1 and "stage" not in g.spec
+                              else 1)
+                sq = sq + jnp.sum(g.v.astype(_F32) ** 2) / r
+            sq = comms.varying_all(sq, mi.all_axes)
+            sq = lax.psum(sq, mi.all_axes)
+            gnorm = jnp.sqrt(sq)
+            scale = jnp.minimum(1.0,
+                                cfg.grad_clip / jnp.maximum(gnorm, 1e-12))
 
         # -- class A (fsdp): local update
         new_fsdp, new_leaves = [], [None] * len(leaves)

@@ -10,6 +10,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.data.pipeline import DataConfig, SyntheticCorpus
 from repro.models.params import Pv
 from repro.train import checkpoint, fault
@@ -86,13 +87,17 @@ def test_step_monitor_straggler_and_heartbeat(tmp_path):
     time.sleep(0.01)
     info = mon.end(0)
     assert not info["straggler"]
+    compiles = json.loads(hb.read_text())["compiles"]
     mon.begin()
+    jax.jit(lambda x: x * 3.0 + 1.0)(jnp.ones(3)).block_until_ready()
     time.sleep(0.06)  # > 2x the 10ms EMA
     info = mon.end(1)
     assert info["straggler"]
     assert mon.stragglers == 1
     data = json.loads(hb.read_text())
     assert data["step"] == 1
+    # the step that compiled shows in the heartbeat
+    assert compiles < data["compiles"] == obs.compile_count()
     assert not fault.heartbeat_stale(hb, timeout_s=60)
     assert fault.heartbeat_stale(tmp_path / "missing.json", 1)
 

@@ -97,7 +97,7 @@ _EXEMPT_FLAGS = {"--xla_force_host_platform_device_count", "--durations"}
 def _flag_exempt(flag: str) -> bool:
     return flag.startswith("--xla") or flag in _EXEMPT_FLAGS
 _ADD_ARG_RE = re.compile(r"add_argument\(\s*['\"](--[a-zA-Z][a-zA-Z0-9_-]*)")
-_FLAG_SRC_DIRS = ("src", "benchmarks", "tools", "examples")
+_FLAG_SRC_DIRS = ("src", "bench", "benchmarks", "tools", "examples")
 
 
 def defined_flags() -> set[str]:
