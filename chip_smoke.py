@@ -8,7 +8,8 @@ Everything runs in this one process, through the entry points a user
 calls.  On one chip the script checks every bq codec kernel (encode,
 decode, fused decode-add-encode with and without the running sum,
 decode-add and gather-decode, at rates 4/8/16/24) and the low-rank matmul
-against their oracles at the size of one minitron-4b FFN gradient, then
+against their oracles at the size of one minitron-4b FFN gradient (the bq
+kernels again with 8 more rows, a partial last row block), then
 trains minitron-4b through ``repro.launch.train.run``.  With
 ``--four-chips`` it runs only the mesh phase: the same model on a
 dp=2 x tp=2 mesh under the uncompressed baseline and two compressed
@@ -118,18 +119,18 @@ def cut_config():
 def check_kernels(rows: int, backend: str) -> None:
     """Every bq kernel and the low-rank matmul against its oracle on
     ``rows`` rows of 128 values.  The bq kernels must be bit-identical
-    to the ``kernels/ref.py`` oracles (``backend="jnp"``)."""
+    to the ``kernels/ref.py`` oracles (``backend="jnp"``), on ``rows`` and
+    again on ``rows + 8``, which ends in a partial row block."""
     import jax
     import jax.numpy as jnp
     from repro.kernels import lowrank, ops
 
     k = jax.random.split(jax.random.key(0), 4)
     # block scales over six decades, and all-zero blocks
-    x = jax.random.normal(k[0], (rows, 128), jnp.float32) \
-        * jnp.exp(3.0 * jax.random.normal(k[1], (rows, 1)))
-    x = x.at[:8].set(0.0)
-    local = jax.random.normal(k[2], (rows, 128), jnp.float32)
-    idx = jax.random.randint(k[3], (rows // 64,), 0, rows // 8)
+    x_all = jax.random.normal(k[0], (rows + 8, 128), jnp.float32) \
+        * jnp.exp(3.0 * jax.random.normal(k[1], (rows + 8, 1)))
+    x_all = x_all.at[:8].set(0.0)
+    local_all = jax.random.normal(k[2], (rows + 8, 128), jnp.float32)
     failed = []
 
     def same(name, got, want):
@@ -145,28 +146,33 @@ def check_kernels(rows: int, backend: str) -> None:
         if bad:
             failed.append(name)
 
-    print(f"kernels ({backend}) against their oracles, {rows} rows x 128:")
-    for bits in BITS:
-        wire = ops.bq_encode_blocks(x, bits, backend="jnp")
-        same(f"bq{bits} encode", ops.bq_encode_blocks(x, bits, backend),
-             wire)
-        same(f"bq{bits} decode", ops.bq_decode_blocks(wire, bits, backend),
-             ops.bq_decode_blocks(wire, bits, "jnp"))
-        for want_sum in (True, False):
-            same(f"bq{bits} decode-add-encode want_sum={want_sum}",
-                 ops.bq_decode_add_encode_blocks(wire, local, bits, backend,
-                                                 want_sum=want_sum),
-                 ops.bq_decode_add_encode_blocks(wire, local, bits, "jnp",
-                                                 want_sum=want_sum))
-        same(f"bq{bits} decode-add",
-             ops.bq_decode_add_blocks(wire, local, bits, backend),
-             ops.bq_decode_add_blocks(wire, local, bits, "jnp"))
-        pool = {key: None if a is None else a.reshape(rows // 8, 8, -1)
-                for key, a in wire.items()}
-        same(f"bq{bits} gather-decode",
-             ops.bq_gather_decode(pool, idx, bits, backend),
-             ops.bq_gather_decode(pool, idx, bits, "jnp"))
+    for n in (rows, rows + 8):
+        x, local = x_all[:n], local_all[:n]
+        idx = jax.random.randint(k[3], (n // 64,), 0, n // 8)
+        print(f"kernels ({backend}) against their oracles, {n} rows x 128:")
+        for bits in BITS:
+            wire = ops.bq_encode_blocks(x, bits, backend="jnp")
+            same(f"bq{bits} encode", ops.bq_encode_blocks(x, bits, backend),
+                 wire)
+            same(f"bq{bits} decode",
+                 ops.bq_decode_blocks(wire, bits, backend),
+                 ops.bq_decode_blocks(wire, bits, "jnp"))
+            for want_sum in (True, False):
+                same(f"bq{bits} decode-add-encode want_sum={want_sum}",
+                     ops.bq_decode_add_encode_blocks(
+                         wire, local, bits, backend, want_sum=want_sum),
+                     ops.bq_decode_add_encode_blocks(
+                         wire, local, bits, "jnp", want_sum=want_sum))
+            same(f"bq{bits} decode-add",
+                 ops.bq_decode_add_blocks(wire, local, bits, backend),
+                 ops.bq_decode_add_blocks(wire, local, bits, "jnp"))
+            pool = {key: None if a is None else a.reshape(n // 8, 8, -1)
+                    for key, a in wire.items()}
+            same(f"bq{bits} gather-decode",
+                 ops.bq_gather_decode(pool, idx, bits, backend),
+                 ops.bq_gather_decode(pool, idx, bits, "jnp"))
 
+    x = x_all[:rows]
     # the plr codec's three products on this gradient's matrix view
     m, ncols = lowrank.mat_shape(rows * 128)
     mat = x.reshape(m, ncols)

@@ -1,10 +1,11 @@
 """Pallas TPU kernels for the block-quantization (bq) codec.
 
 Layout contract: the ops layer reshapes every tensor into a 2-D
-``(M, BLOCK=128)`` matrix (padding the tail).  Kernels tile it as
-``(TILE_M, 128)`` VMEM blocks — 128 matches the VPU lane width, TILE_M=8
-matches the sublane count, so a tile is exactly one (8, 128) vreg-shaped
-panel and the per-block max-abs reduction stays within registers.
+``(M, BLOCK=128)`` matrix, M padded to a multiple of TILE_M=8 (the
+sublane count).  A grid step takes a ``(R, 128)`` VMEM block of many rows,
+R from :func:`block_rows` (the VMEM its blocks take), with a ragged last
+block; 128 matches the VPU lane width, so each row's max-abs reduction
+stays within its lanes.
 
 Four kernels:
   * ``bq_encode``            x -> (q_hi[, q_lo], scale)
@@ -40,7 +41,13 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.ref import BLOCK, _INV_QMAX, _QMAX
 
-TILE_M = 8  # sublane-aligned rows per grid step
+# Padding multiple of the (M, 128) layout: one sublane group.  State sizes,
+# ring chunks and paged pools are whole multiples of it; a grid step takes
+# block_rows() rows, many of these.
+TILE_M = 8
+# VMEM for the double-buffered blocks of one grid step: half of v5e's
+# 16 MiB scoped default, so the kernel body's temporaries fit beside them.
+VMEM_BLOCK_BYTES = 8 * 2**20
 
 
 def _hi_dtype(bits: int):
@@ -157,76 +164,80 @@ def _da24_kernel(qhi_ref, qlo_ref, scale_ref, local_ref, sum_o, *, bits):
 # pallas_call wrappers (operate on (M, 128) matrices, M % TILE_M == 0)
 # --------------------------------------------------------------------------
 
-def _mat_spec():
-    return pl.BlockSpec((TILE_M, BLOCK), lambda i: (i, 0))
+def block_rows(m: int, dtypes) -> int:
+    """Rows of one grid step for a kernel whose operands and results, one
+    row each, have ``dtypes``: the largest power of two whose
+    double-buffered blocks fit in ``VMEM_BLOCK_BYTES``, or all ``m`` rows.
+
+    A row of any block takes 128 lanes in VMEM: a ``(R, 1)`` f32 scale
+    costs 512 bytes a row, as much as an f32 row of values."""
+    row = 2 * sum(BLOCK * jnp.dtype(d).itemsize for d in dtypes)
+    r = TILE_M
+    while 2 * r * row <= VMEM_BLOCK_BYTES:
+        r *= 2
+    return min(r, m)
 
 
-def _q_spec(bits):
-    return pl.BlockSpec((TILE_M, _hi_width(bits)), lambda i: (i, 0))
-
-
-def _scale_spec():
-    return pl.BlockSpec((TILE_M, 1), lambda i: (i, 0))
-
-
-def _grid(m: int):
+def _call(kernel, bits, args, out_shape, interpret):
+    """Run ``kernel`` over the rows of ``args`` in blocks of
+    :func:`block_rows`; the last block may be ragged (rows are quantized
+    independently, so its rows past M only give writes that are dropped)."""
+    m = args[0].shape[0]
     assert m % TILE_M == 0, f"rows {m} not a multiple of {TILE_M}"
-    return (m // TILE_M,)
+    r = block_rows(m, [a.dtype for a in args] + [o.dtype for o in out_shape])
+
+    def spec(width):
+        return pl.BlockSpec((r, width), lambda i: (i, 0))
+
+    return pl.pallas_call(
+        functools.partial(kernel, bits=bits),
+        grid=(pl.cdiv(m, r),),
+        in_specs=[spec(a.shape[1]) for a in args],
+        out_specs=[spec(o.shape[1]) for o in out_shape],
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*args)
+
+
+def _wire_shapes(m: int, bits: int):
+    """Shapes of the (q_hi[, q_lo], scale) planes of M rows."""
+    hi = jax.ShapeDtypeStruct((m, _hi_width(bits)), _hi_dtype(bits))
+    scale = jax.ShapeDtypeStruct((m, 1), jnp.float32)
+    if bits == 24:
+        return [hi, jax.ShapeDtypeStruct((m, BLOCK), jnp.uint8), scale]
+    return [hi, scale]
+
+
+def _wire_args(q_hi, q_lo, scale, bits: int):
+    return [q_hi, q_lo, scale] if bits == 24 else [q_hi, scale]
+
+
+def _f32(m: int):
+    return jax.ShapeDtypeStruct((m, BLOCK), jnp.float32)
+
+
+def _unwire(out, bits: int):
+    """(q_hi, q_lo|None, scale) of a kernel's leading wire outputs."""
+    if bits == 24:
+        return out[0], out[1], out[2]
+    return out[0], None, out[1]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def bq_encode_pallas(x2d: jnp.ndarray, bits: int, interpret: bool = False):
     """(M, 128) f32 -> (q_hi[, q_lo], scale). Returns (q_hi, q_lo|None, scale)."""
-    m = x2d.shape[0]
-    if bits == 24:
-        out = pl.pallas_call(
-            functools.partial(_encode24_kernel, bits=bits),
-            grid=_grid(m),
-            in_specs=[_mat_spec()],
-            out_specs=[_mat_spec(), _mat_spec(), _scale_spec()],
-            out_shape=[
-                jax.ShapeDtypeStruct((m, BLOCK), jnp.int16),
-                jax.ShapeDtypeStruct((m, BLOCK), jnp.uint8),
-                jax.ShapeDtypeStruct((m, 1), jnp.float32),
-            ],
-            interpret=interpret,
-        )(x2d)
-        return out[0], out[1], out[2]
-    out = pl.pallas_call(
-        functools.partial(_encode_kernel, bits=bits),
-        grid=_grid(m),
-        in_specs=[_mat_spec()],
-        out_specs=[_q_spec(bits), _scale_spec()],
-        out_shape=[
-            jax.ShapeDtypeStruct((m, _hi_width(bits)), _hi_dtype(bits)),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2d)
-    return out[0], None, out[1]
+    kern = _encode24_kernel if bits == 24 else _encode_kernel
+    out = _call(kern, bits, [x2d], _wire_shapes(x2d.shape[0], bits),
+                interpret)
+    return _unwire(out, bits)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def bq_decode_pallas(q_hi, q_lo, scale, bits: int, interpret: bool = False):
     """(q_hi[, q_lo], scale) -> (M, 128) f32."""
-    m = q_hi.shape[0]
-    if bits == 24:
-        return pl.pallas_call(
-            functools.partial(_decode24_kernel, bits=bits),
-            grid=_grid(m),
-            in_specs=[_mat_spec(), _mat_spec(), _scale_spec()],
-            out_specs=_mat_spec(),
-            out_shape=jax.ShapeDtypeStruct((m, BLOCK), jnp.float32),
-            interpret=interpret,
-        )(q_hi, q_lo, scale)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, bits=bits),
-        grid=_grid(m),
-        in_specs=[_q_spec(bits), _scale_spec()],
-        out_specs=_mat_spec(),
-        out_shape=jax.ShapeDtypeStruct((m, BLOCK), jnp.float32),
-        interpret=interpret,
-    )(q_hi, scale)
+    kern = _decode24_kernel if bits == 24 else _decode_kernel
+    return _call(kern, bits, _wire_args(q_hi, q_lo, scale, bits),
+                 [_f32(q_hi.shape[0])], interpret)[0]
 
 
 @functools.partial(jax.jit,
@@ -241,66 +252,21 @@ def bq_decode_add_encode_pallas(q_hi, q_lo, scale, local, bits: int,
     m = q_hi.shape[0]
     if bits == 24:
         kern = _dae24_kernel if want_sum else _daew24_kernel
-        specs = [_mat_spec(), _mat_spec(), _scale_spec()]
-        shapes = [
-            jax.ShapeDtypeStruct((m, BLOCK), jnp.int16),
-            jax.ShapeDtypeStruct((m, BLOCK), jnp.uint8),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
-        ]
-        if want_sum:
-            specs.append(_mat_spec())
-            shapes.append(jax.ShapeDtypeStruct((m, BLOCK), jnp.float32))
-        out = pl.pallas_call(
-            functools.partial(kern, bits=bits),
-            grid=_grid(m),
-            in_specs=[_mat_spec(), _mat_spec(), _scale_spec(), _mat_spec()],
-            out_specs=specs,
-            out_shape=shapes,
-            interpret=interpret,
-        )(q_hi, q_lo, scale, local)
-        return out[0], out[1], out[2], out[3] if want_sum else None
-    kern = _dae_kernel if want_sum else _daew_kernel
-    specs = [_q_spec(bits), _scale_spec()]
-    shapes = [
-        jax.ShapeDtypeStruct((m, _hi_width(bits)), _hi_dtype(bits)),
-        jax.ShapeDtypeStruct((m, 1), jnp.float32),
-    ]
-    if want_sum:
-        specs.append(_mat_spec())
-        shapes.append(jax.ShapeDtypeStruct((m, BLOCK), jnp.float32))
-    out = pl.pallas_call(
-        functools.partial(kern, bits=bits),
-        grid=_grid(m),
-        in_specs=[_q_spec(bits), _scale_spec(), _mat_spec()],
-        out_specs=specs,
-        out_shape=shapes,
-        interpret=interpret,
-    )(q_hi, scale, local)
-    return out[0], None, out[1], out[2] if want_sum else None
+    else:
+        kern = _dae_kernel if want_sum else _daew_kernel
+    out = _call(kern, bits, _wire_args(q_hi, q_lo, scale, bits) + [local],
+                _wire_shapes(m, bits) + ([_f32(m)] if want_sum else []),
+                interpret)
+    return (*_unwire(out, bits), out[-1] if want_sum else None)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))
 def bq_decode_add_pallas(q_hi, q_lo, scale, local, bits: int,
                          interpret: bool = False):
     """Final ring hop: local + decode(wire) -> (M, 128) f32 sum only."""
-    m = q_hi.shape[0]
-    if bits == 24:
-        return pl.pallas_call(
-            functools.partial(_da24_kernel, bits=bits),
-            grid=_grid(m),
-            in_specs=[_mat_spec(), _mat_spec(), _scale_spec(), _mat_spec()],
-            out_specs=_mat_spec(),
-            out_shape=jax.ShapeDtypeStruct((m, BLOCK), jnp.float32),
-            interpret=interpret,
-        )(q_hi, q_lo, scale, local)
-    return pl.pallas_call(
-        functools.partial(_da_kernel, bits=bits),
-        grid=_grid(m),
-        in_specs=[_q_spec(bits), _scale_spec(), _mat_spec()],
-        out_specs=_mat_spec(),
-        out_shape=jax.ShapeDtypeStruct((m, BLOCK), jnp.float32),
-        interpret=interpret,
-    )(q_hi, scale, local)
+    kern = _da24_kernel if bits == 24 else _da_kernel
+    return _call(kern, bits, _wire_args(q_hi, q_lo, scale, bits) + [local],
+                 [_f32(q_hi.shape[0])], interpret)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "interpret"))

@@ -244,3 +244,41 @@ def test_restore_opt_refuses_a_v_layout_it_cannot_read(trainer, state, mesh,
     out = capsys.readouterr().out
     assert "WARNING: optimizer state not portable to this topology" in out
     assert_tree_equal(got, trainer.opt_init(params))
+
+
+# CFG's 8-bit state on one device, laid out as it always has been: m and
+# sqrt(v) are bq8 wires of 616 rows of 128, the f32 master 78,848 values
+STATE8_ROWS, MASTER8 = 616, 78_848
+
+
+def test_8bit_state_layout_and_a_checkpoint_in_it(trainer8, mesh, tmp_path,
+                                                  capsys):
+    """Adam.init gives the fixed layout, and a checkpoint written in it
+    (arrays of the literal shapes, not taken from this build) restores
+    as saved."""
+    params, ostate, _ = trainer8.init_all(jax.random.key(0))
+    for k in ("m", "v"):
+        assert ostate[k]["q_hi"].shape == (STATE8_ROWS, 128)
+        assert ostate[k]["q_hi"].dtype == np.int8
+        assert ostate[k]["q_lo"] is None
+        assert ostate[k]["scale"].shape == (STATE8_ROWS, 1)
+        assert ostate[k]["scale"].dtype == np.float32
+    assert ostate["master"].shape == (MASTER8,)
+    rng = np.random.default_rng(0)
+
+    def wire():
+        return {"q_hi": rng.integers(-127, 128, (STATE8_ROWS, 128),
+                                     dtype=np.int8),
+                "q_lo": None,
+                "scale": rng.uniform(0.5, 2.0, (STATE8_ROWS, 1))
+                .astype(np.float32)}
+
+    saved = dict(ostate, m=wire(), v=wire(),
+                 master=rng.standard_normal(MASTER8).astype(np.float32))
+    odir = str(tmp_path / "opt")
+    checkpoint.save(odir, 5, saved, extra={"v_layout": "sqrt_v"})
+    got = _restore_opt(trainer8, params, odir, 5, mesh, checkpoint)
+    out = capsys.readouterr().out
+    assert "restored optimizer state at step 5" in out
+    assert "WARNING" not in out
+    assert_tree_equal(got, saved)

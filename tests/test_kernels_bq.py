@@ -13,7 +13,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro.kernels import bq, ops, ref
 from repro.core import codecs
 
 BITS = (4, 8, 16, 24)
@@ -110,6 +110,80 @@ def test_block_ops_take_leading_dims(bits):
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
+KERNELS = ("encode", "decode", "decode_add_encode", "decode_add_encode_wire",
+           "decode_add", "gather_decode")
+
+
+def _kernel(kernel, bits, x, local, idx, backend):
+    """One bq kernel through the ops layer, on the wire the oracle makes
+    of ``x``; gather-decode reads it as a pool of 8-row blocks."""
+    if kernel == "encode":
+        return ops.bq_encode_blocks(x, bits, backend)
+    wire = ops.bq_encode_blocks(x, bits, "jnp")
+    if kernel == "decode":
+        return ops.bq_decode_blocks(wire, bits, backend)
+    if kernel.startswith("decode_add_encode"):
+        return ops.bq_decode_add_encode_blocks(
+            wire, local, bits, backend, want_sum=kernel == "decode_add_encode")
+    if kernel == "decode_add":
+        return ops.bq_decode_add_blocks(wire, local, bits, backend)
+    pool = {k: None if a is None else a.reshape(-1, 8, a.shape[-1])
+            for k, a in wire.items()}
+    return ops.bq_gather_decode(pool, idx, bits, backend)
+
+
+def _pallas_call(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v if hasattr(v, "eqns") else None)
+            found = sub is not None and _pallas_call(sub)
+            if found:
+                return found
+    return None
+
+
+def _rows_per_step(kernel, bits):
+    """The rows of one grid step that the kernel chooses on a large input."""
+    m = 1 << 16
+    args = (jax.ShapeDtypeStruct((m, 128), jnp.float32),
+            jax.ShapeDtypeStruct((m, 128), jnp.float32),
+            jax.ShapeDtypeStruct((m // 8,), jnp.int32))
+    closed = jax.make_jaxpr(lambda *a: _kernel(kernel, bits, *a,
+                                               "pallas_interpret"))(*args)
+    grid = _pallas_call(closed.jaxpr).params["grid_mapping"]
+    rows = {bm.block_shape[0].block_size for bm in grid.block_mappings}
+    assert len(rows) == 1 and grid.grid == (m // min(rows),), grid
+    return rows.pop()
+
+
+@pytest.mark.parametrize("bits", BITS)
+@pytest.mark.parametrize("kernel", KERNELS)
+@pytest.mark.parametrize("rows", ["2R+8", "3R-8", "R-8"])
+def test_kernel_over_row_blocks_matches_ref(kernel, bits, rows):
+    """Several row blocks that end in a ragged one, and fewer rows than a
+    block: bit-identical to the oracle either way."""
+    r = _rows_per_step(kernel, bits)
+    assert r > 8 and r % 8 == 0
+    m = {"2R+8": 2 * r + 8, "3R-8": 3 * r - 8, "R-8": r - 8}[rows]
+    rng = np.random.default_rng(m + bits)
+    # block scales over six decades, and all-zero rows
+    x = rng.normal(size=(m, 128)) * np.exp(3.0 * rng.normal(size=(m, 1)))
+    x[:8] = 0.0
+    local = rng.normal(size=(m, 128))
+    idx = rng.integers(0, m // 8, size=m // 8)
+    x, local = (jnp.asarray(a, jnp.float32) for a in (x, local))
+    idx = jnp.asarray(idx, jnp.int32)
+    got = _kernel(kernel, bits, x, local, idx, "pallas_interpret")
+    want = _kernel(kernel, bits, x, local, idx, "jnp")
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 @pytest.mark.parametrize("bits", BITS)
 def test_error_bound(bits):
     x = jnp.asarray(_rand((2048,), np.float32, seed=3, scale=100.0))
@@ -203,3 +277,24 @@ def test_wire_nbytes():
     assert ops.wire_nbytes(w8) == 1024 + 8 * 4        # int8 + 8 block scales
     assert ops.wire_nbytes(w24) == 1024 * 3 + 8 * 4   # int16+uint8 planes
     assert ops.wire_nbytes(codecs.get("none").encode(x)[0]) == 4096
+
+
+# The (M, 128) layout pads to 8 rows however many rows a grid step takes:
+# state sizes, ring chunks, wire bytes and checkpoints rest on it.
+@pytest.mark.parametrize("n,rows", [
+    (1, 8), (1024, 8), (1025, 16), (221_184 * 128 + 1, 221_192),
+    (523_791_360, 4_092_120)])      # the minitron-4b cell's 8-bit state
+def test_padding_multiple_is_eight_rows(n, rows):
+    assert bq.TILE_M == 8
+    assert ops.padded_rows(n) == rows
+
+
+def test_bq8_wire_of_the_minitron_state_is_unchanged():
+    wire = jax.eval_shape(
+        lambda x: codecs.get("bq8").encode(x)[0],
+        jax.ShapeDtypeStruct((523_791_360,), jnp.float32))
+    assert wire["q_hi"].shape == (4_092_120, 128)
+    assert wire["q_hi"].dtype == jnp.int8 and wire["q_lo"] is None
+    assert wire["scale"].shape == (4_092_120, 1)
+    assert wire["scale"].dtype == jnp.float32
+    assert ops.wire_nbytes(wire) == 523_791_360 + 4 * 4_092_120
