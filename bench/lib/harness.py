@@ -21,7 +21,7 @@ import sys
 import tempfile
 import time
 
-from bench.lib import data, flops, reference, spec, weights
+from bench.lib import data, flops, reference, scopes, spec, trace, weights
 
 # ArchConfig field each sizes key of a configuration file must match
 _FIELDS = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
@@ -72,16 +72,24 @@ class Cell:
     """The files of one cell, read by name."""
 
     def __init__(self, name: str, bench_dir=spec.BENCH, overrides=None):
+        # tests shrink a cell: ``overrides`` updates the cell file ("w"),
+        # then the files it names ("traffic", "c")
+        overrides = overrides or {}
         self.name = name
         self.w = spec.workload(name, bench_dir)
+        self.w.update(overrides.get("w", {}))
         self.traffic = spec.traffic(self.w["traffic"], bench_dir)
+        self.traffic.update(overrides.get("traffic", {}))
         self.c = spec.config(self.w["config"], bench_dir)
-        for k, v in (overrides or {}).items():      # tests shrink a cell
-            getattr(self, k).update(v)
+        self.c.update(overrides.get("c", {}))
         self.ref = spec.reference(self.w["config"], bench_dir)
         self.chips = self.w["chips"]
         self.tokens_per_step = self.traffic["global_batch"] \
             * self.traffic["seq"]
+        # counted here, so that a configuration the count cannot read
+        # fails before anything is compiled
+        self.flops_per_token = flops.train_per_token(self.c,
+                                                     self.traffic["seq"])
 
 
 def program_arch(c: dict):
@@ -92,7 +100,8 @@ def program_arch(c: dict):
     from repro.models.config import BlockGroup
     p = c["program"]
     cut = dict(p["cut"])
-    groups = tuple(BlockGroup(g["kind"], g["n"]) for g in cut.pop("groups"))
+    groups = tuple(BlockGroup(g["kind"], g["n"], g.get("window", 0))
+                   for g in cut.pop("groups"))
     arch = configs.get(p["arch"]).replace(groups=groups, **cut)
     for key, field in _FIELDS.items():
         if key in c and getattr(arch, field) != c[key]:
@@ -495,7 +504,7 @@ def _traced(prog, compiled, state, corpus, counter, cell, mem, wire,
     """The traced steps: profiler on, ``trace_steps`` steps back to back,
     reduced to the facts the per-layer metrics read."""
     import jax
-    from bench.lib import codec_bytes, peaks, trace
+    from bench.lib import codec_bytes, peaks
     k = cell.w["trace_steps"]
     out_dir = tempfile.mkdtemp(prefix="bench_trace_")
     try:
@@ -510,24 +519,31 @@ def _traced(prog, compiled, state, corpus, counter, cell, mem, wire,
         log(f"trace planes and lines: {compact['lines']}")
         if save_trace:
             trace.save(compact, save_trace)
-        red = trace.reduce(compact)
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
     kind = jax.devices()[0].device_kind
     pk = peaks.peak(kind)
-    c = cell.c
-    ftok = flops.train_per_token(c, cell.traffic["seq"])
-    facts = {
+    facts = reduce_trace(compact, scopes.scope_map(compiled.as_text()))
+    facts.update({
         "_state": state, "_losses": losses, "steps": n,
-        "busy_s": red["busy_s"], "window_s": red["window_s"],
-        "breakdown": red["breakdown"], "trace": red,
         "chips": cell.chips, "peak": pk,
         "tokens_per_step": cell.tokens_per_step,
-        "flops_per_token": ftok,
+        "flops_per_token": cell.flops_per_token,
         "wire_bytes_per_step": sum(wire.values()),
         "step_bytes": (mem.argument_size_in_bytes + mem.output_size_in_bytes
                        - mem.alias_size_in_bytes + mem.temp_size_in_bytes),
         "codec_bytes_per_step": codec_bytes.per_step(
             prog.events, cell.w["opt"]["state_bits"], prog.flat_len),
-    }
+    })
     return facts
+
+
+def reduce_trace(compact: dict, smap: dict) -> dict:
+    """The facts a traced window gives: the trace's reduction
+    (``trace.reduce``) and the device time of each named scope
+    (``scopes.reduce``, ``smap`` from the compiled step's text), both over
+    all the traced steps."""
+    red = trace.reduce(compact)
+    return {"busy_s": red["busy_s"], "window_s": red["window_s"],
+            "breakdown": red["breakdown"], "trace": red,
+            "scopes": scopes.reduce(compact, smap)}

@@ -9,8 +9,9 @@ drives a run (no reference is run, so nothing is checked): set-up through
 the first three steps, with the program's compile clock
 (``repro.obs.snapshot``) read where ``setup_s`` is taken; an untraced
 window of ``--seconds``; then, the profiler on, the cell's ``trace_steps``
-steps.  The trace is reduced by ``bench/lib/trace.py`` and, joined to the
-compiled step's text, by ``bench/lib/scopes.py``.  It prints one JSON
+steps.  The trace is reduced as a traced run of ``bench/run.py`` reduces
+it (``harness.reduce_trace``: ``bench/lib/trace.py`` and, joined to the
+compiled step's text, ``bench/lib/scopes.py``).  It prints one JSON
 line: set-up and its compile split, the host's seconds a step over the
 window and over as many steps as are traced with the profiler off and
 then on, and the device time a step of each scope, in total
@@ -106,8 +107,8 @@ def _traced(prog, compiled, state, corpus, counter, cell, first_step, save,
     finally:
         shutil.rmtree(out_dir, ignore_errors=True)
     smap = scopes.scope_map(compiled.as_text())
-    red = trace.reduce(compact)
-    sc = scopes.reduce(compact, smap)
+    facts = harness.reduce_trace(compact, smap)
+    red, sc = facts["trace"], facts["scopes"]
     names = {trace.op_name(t) for ops in compact["devices"]
              for t, _, _ in ops}
     codec = sorted({scopes.scope_of(smap[x])[0] if x in smap else None

@@ -1,32 +1,58 @@
 """Tiny versions of the benchmark's configurations, for CPU tests: the
-same program paths and references at widths a test run can hold."""
+same program paths and references at widths a test run can hold.
 
-MINITRON = {
-    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 2,
-    "head_dim": 16, "intermediate_size": 128, "num_hidden_layers": 2,
-    "vocab_size": 512, "plan": [{"kind": "attn", "n": 2}],
-    "program": {"arch": "minitron-4b",
-                "cut": {"n_layers": 2, "vocab_size": 512, "d_model": 64,
-                        "n_heads": 4, "n_kv_heads": 2, "head_dim": 16,
-                        "d_ff": 128, "groups": []}}}
+Each is a file ``tests/bench/tiny/<name>.json``:
 
-ZAMBA2 = {
-    "hidden_size": 64, "num_attention_heads": 4, "num_key_value_heads": 4,
-    "head_dim": 16, "intermediate_size": 128, "num_hidden_layers": 4,
-    "vocab_size": 512, "mamba_d_state": 8, "mamba_headdim": 8,
-    "plan": [{"kind": "mamba", "n": 2}, {"kind": "attn", "n": 1},
-             {"kind": "mamba", "n": 2}, {"kind": "attn", "n": 1}],
-    "program": {"arch": "zamba2-1.2b",
-                "cut": {"n_layers": 4, "vocab_size": 512, "d_model": 64,
-                        "n_heads": 4, "n_kv_heads": 4, "head_dim": 16,
-                        "d_ff": 128, "ssm_state": 8, "ssm_head_dim": 8,
-                        "groups": [{"kind": "mamba", "n": 2},
-                                   {"kind": "shared_attn", "n": 1},
-                                   {"kind": "mamba", "n": 2},
-                                   {"kind": "shared_attn", "n": 1}]}}}
+    cell        the cell file (``bench/workloads``) that runs it
+    w, c        overrides of that cell file and of its configuration
+    traffic     overrides of its traffic, for the cell's own tests
+    reference   the traffic of the float32 reference test
 
-# cell of BENCHMARK.json -> overrides of its configuration and traffic
-CELLS = {
-    "minitron4b.1chip.opt8": {"c": MINITRON,
-                              "traffic": {"seq": 64, "global_batch": 4}},
-}
+A file named after a cell of ``BENCHMARK.json`` is that cell's tiny
+version: the control, fault and scope tests run it (``CELLS``).  Any
+other file is named after the configuration it shrinks.  Each
+configuration's first file gives the reference test its case
+(``REFERENCE``).  A later cell gets these tests by adding its file.
+"""
+
+import pathlib
+import sys
+
+ROOT = pathlib.Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from bench.lib import spec  # noqa: E402
+
+TINY = pathlib.Path(__file__).resolve().parent / "tiny"
+_OVERRIDES = ("w", "c", "traffic")
+
+
+def load(tiny=TINY, root=ROOT):
+    """(``CELLS``, ``REFERENCE``) from the files in ``tiny``, for the
+    benchmark at ``root``."""
+    cells = {w["name"] for w in spec.benchmark(root)["workloads"]}
+    by_cell, by_config = {}, {}
+    for path in sorted(pathlib.Path(tiny).glob("*.json")):
+        t = spec.load_json(path)
+        config = t.get("w", {}).get("config") \
+            or spec.workload(t["cell"], root / "bench")["config"]
+        if path.stem in cells:
+            if t["cell"] != path.stem:
+                raise ValueError(f"{path} shrinks cell {path.stem!r} but "
+                                 f"names {t['cell']!r}")
+            by_cell[path.stem] = {k: t[k] for k in _OVERRIDES if k in t}
+        elif path.stem != config:
+            raise ValueError(f"{path} is named after no cell of "
+                             f"BENCHMARK.json, nor after its configuration "
+                             f"{config!r}")
+        if config not in by_config:
+            by_config[config] = (t["cell"], {
+                **{k: t[k] for k in ("w", "c") if k in t},
+                "traffic": t["reference"]})
+    return by_cell, by_config
+
+
+# cell of BENCHMARK.json -> overrides of its files;
+# configuration -> (cell file that runs it, overrides at the reference size)
+CELLS, REFERENCE = load()

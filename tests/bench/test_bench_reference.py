@@ -18,20 +18,13 @@ from bench.lib import harness, reference  # noqa: E402
 
 harness.use_program(harness.spec.ROOT)
 
-# (configuration, a cell file that runs it) -> tiny sizes
-CASES = {"minitron-4b": ("minitron4b.1chip.opt8", bench_tiny.MINITRON, 64),
-         "zamba2-1.2b": ("minitron4b.1chip.opt8", bench_tiny.ZAMBA2, 256)}
+CASES = bench_tiny.REFERENCE
 
 
 def _numbers(config, dtype, monkeypatch):
-    cell_name, tiny, seq = CASES[config]
-    c = copy.deepcopy(tiny)
-    c["program"]["cut"]["dtype"] = dtype
-    cell = harness.Cell(cell_name, overrides={
-        "c": c, "traffic": {"seq": seq, "global_batch": 2}})
-    cell.w["config"] = config
-    cell.c = {**harness.spec.config(config), **c}
-    cell.ref = harness.spec.reference(config)
+    cell_name, overrides = copy.deepcopy(CASES[config])
+    overrides["c"]["program"]["cut"]["dtype"] = dtype
+    cell = harness.Cell(cell_name, overrides=overrides)
     cell.w["opt"] = dict(cell.w["opt"], state_bits=32)
     if dtype == "float32":
         layout = cell.ref.layout

@@ -237,6 +237,14 @@ RECORDED = (ROOT / "bench" / "testdata" /
             "minitron4b.1chip.opt8.scopes.trace.json.gz")
 
 
+# device ms a step of each scope that bench/scope_table.py read on the
+# chip for the recorded trace's four steps
+SCOPE_TABLE_MS = {
+    "attention": 178.9267, "mlp": 70.559, "norm": 1.7507, "embed": 3.3748,
+    "head": 30.3239, "optimizer": 19.3668, "optimizer/clip": 1.0728,
+    "optimizer/state_codec": 483.6204, "optimizer/update": 16.4743}
+
+
 def test_scopes_of_a_trace_recorded_on_the_chip():
     """Four steps of minitron4b.1chip.opt8 on a TPU v5e, saved with the
     scope path of each of its operations (``bench/scope_table.py
@@ -245,11 +253,7 @@ def test_scopes_of_a_trace_recorded_on_the_chip():
     red = trace.reduce(t)
     r = scopes.reduce(t, t["scope_map"])
     ms = {s: 1e3 * row["s"] / 4 for s, row in r["scopes"].items()}
-    assert ms == pytest.approx({
-        "attention": 178.9267, "mlp": 70.559, "norm": 1.7507,
-        "embed": 3.3748, "head": 30.3239, "optimizer": 19.3668,
-        "optimizer/clip": 1.0728, "optimizer/state_codec": 483.6204,
-        "optimizer/update": 16.4743}, abs=1e-3)
+    assert ms == pytest.approx(SCOPE_TABLE_MS, abs=1e-3)
     att = r["scopes"]["attention"]
     assert [1e3 * att[p] / 4 for p in ("fwd", "bwd", "recompute")] == \
         pytest.approx([41.0036, 95.5214, 42.4016], abs=1e-3)
@@ -264,3 +268,20 @@ def test_scopes_of_a_trace_recorded_on_the_chip():
     assert {scopes.scope_of(t["scope_map"][x]) for x in codec} == \
         {("optimizer/state_codec", "fwd")}
     assert r["scopes"]["optimizer/state_codec"]["s"] > red["codec_s"]
+
+
+def test_traced_facts_carry_the_scope_table():
+    """The facts of a traced run (``harness.reduce_trace``, as
+    ``harness._traced`` builds them) hold, for the recorded trace, the
+    device time of each scope that ``bench/scope_table.py`` read, and the
+    trace's own reduction beside it."""
+    t = trace.read_saved(str(RECORDED))
+    f = harness.reduce_trace(t, t["scope_map"])
+    ms = {s: 1e3 * row["s"] / 4 for s, row in f["scopes"]["scopes"].items()}
+    assert ms == pytest.approx(SCOPE_TABLE_MS, abs=1e-3)
+    assert 1e3 * f["scopes"]["unscoped_s"] / 4 == \
+        pytest.approx(32.7632, abs=1e-3)
+    red = trace.reduce(t)
+    assert f["trace"] == red
+    assert (f["busy_s"], f["window_s"], f["breakdown"]) == \
+        (red["busy_s"], red["window_s"], red["breakdown"])
